@@ -185,7 +185,9 @@ err = float(jnp.max(jnp.abs(f1(q, q, q, wo) - f2(q, q, q, wo))))
 out['max_err'] = err
 print(json.dumps(out))
 """
-    env = dict(os.environ,
+    # the child's devices are fake CPU ones: on a TPU host it must not
+    # try to take the chip this process already holds
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=os.path.join(ROOT, "src"))
     res = subprocess.run([sys.executable, "-c", code], env=env,

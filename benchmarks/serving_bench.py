@@ -922,7 +922,9 @@ def distributed(arch: str = "gemma2-2b", n_requests: int = 6,
     """Tensor-parallel serving section: spawns a child process with
     ``devices`` forced fake CPU devices (the main process keeps its
     single-device view) and collects its report."""
-    env = dict(os.environ,
+    # the child's devices are fake CPU ones: on a TPU host it must not
+    # try to take the chip this process already holds
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH=os.pathsep.join(
                    [os.path.join(REPO_ROOT, "src"), REPO_ROOT]))

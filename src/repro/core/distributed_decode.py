@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import shard_map as _shard_map
 
 NEG_INF = -1e30
 
@@ -170,7 +169,7 @@ def context_parallel_decode(mesh, q, k_cache, v_cache, kv_len, *,
         cp_decode_body, axis_name=axis_name, window=window,
         softcap=softcap, scale=scale, global_seq=k_cache.shape[2])
     ba = tuple(a for a in batch_axes if a in mesh.axis_names)
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(ba, None, None), P(ba, None, axis_name, None),
                   P(ba, None, axis_name, None), P(ba)),

@@ -37,9 +37,9 @@ def fast_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def default_paged_impl() -> str:
-    """Best paged-decode impl for the current backend: the Pallas kernel
-    on TPU, the jittable gather-reference everywhere else (the kernel
-    still runs off-TPU via interpret=True, but only for verification)."""
+    """Best paged impl for the current backend: the compiled Pallas
+    kernel on TPU, the jittable gather-reference everywhere else.  The
+    kernel runs off-TPU only when asked for by name ("paged_interpret")."""
     return "paged" if jax.default_backend() == "tpu" else "paged_reference"
 
 
@@ -57,8 +57,9 @@ def fast_attention_prefill_paged(q: jax.Array, k_pages: jax.Array,
     q: (B, Sq, Hq, D) layer-layout chunk queries; pages
     (Hkv, P, page_size, D); page_table (B, n_kv) int32; pos_start /
     kv_len: (B,) int32 *runtime* offsets -- one jit trace serves every
-    chunk position of every prompt length.  "paged" runs the Pallas
-    kernel (scalar-prefetched page table, auto-interpret off TPU);
+    chunk position of every prompt length.  "paged" runs the compiled
+    Pallas kernel (scalar-prefetched page table; TPU only),
+    "paged_interpret" the same kernel in the Pallas interpreter;
     "paged_reference" gathers the owned pages and runs the online-softmax
     flash reference -- the jittable CPU path.  Returns (B, Sq, Hq, D).
     """
@@ -70,12 +71,10 @@ def fast_attention_prefill_paged(q: jax.Array, k_pages: jax.Array,
             window=window, softcap=softcap, scale=scale)
     elif impl in ("paged", "paged_interpret"):
         from repro.kernels.fastattn.ops import fastattn_paged_prefill
-        interpret = (impl == "paged_interpret"
-                     or jax.default_backend() != "tpu")
         out = fastattn_paged_prefill(
             qT, k_pages, v_pages, page_table, pos_start, kv_len,
             window=window, softcap=softcap, scale=scale, block_q=block_q,
-            interpret=interpret)
+            interpret=impl == "paged_interpret")
     else:
         raise ValueError(f"unknown paged prefill impl {impl!r}")
     return out.transpose(0, 2, 1, 3)
@@ -101,8 +100,9 @@ def fast_attention_decode(q: jax.Array, k_cache: jax.Array,
     caches are instead global page pools (Hkv, P, page_size, D) shared by
     every sequence, and ``page_table`` (B, n_kv) int32 maps each
     sequence's logical KV block to its physical page (serving/paged_cache
-    owns the table).  "paged" runs the Pallas kernel (auto interpret off
-    TPU); "paged_reference" gathers the owned pages into a dense view and
+    owns the table).  "paged" runs the compiled Pallas kernel (TPU
+    only), "paged_interpret" the same kernel in the Pallas interpreter;
+    "paged_reference" gathers the owned pages into a dense view and
     reuses the dense oracle -- the jittable CPU path.
 
     The reference path works IN PLACE on the (B, S, Hkv, D) bf16 cache --
@@ -122,12 +122,10 @@ def fast_attention_decode(q: jax.Array, k_cache: jax.Array,
                 kv_len, window=window, softcap=softcap, scale=scale)
             return out.transpose(0, 2, 1, 3)
         from repro.kernels.flash_decode.ops import paged_flash_decode
-        interpret = (impl == "paged_interpret"
-                     or jax.default_backend() != "tpu")
         out = paged_flash_decode(
             q.transpose(0, 2, 1, 3)[:, :, 0], k_cache, v_cache, page_table,
             kv_len, window=window, softcap=softcap, scale=scale,
-            interpret=interpret)[:, :, None]
+            interpret=impl == "paged_interpret")[:, :, None]
         return out.transpose(0, 2, 1, 3)
 
     if impl in ("pallas", "interpret"):

@@ -31,9 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import axis_size as _axis_size
-from repro.core.compat import shard_map as _shard_map
-
 
 def chunk_sizes(total: int, n_chunks: int, first_frac: float = 0.5,
                 align: int = 1) -> Sequence[int]:
@@ -133,7 +130,7 @@ def tiled_matmul_reducescatter(x: jax.Array, w: jax.Array, axis_name: str, *,
     Output rows are scattered along the axis: (T, D) -> (T/axis, D).
     """
     t = x.shape[0]
-    axis_size = _axis_size(axis_name)
+    axis_size = jax.lax.axis_size(axis_name)
     if t % axis_size:
         raise ValueError(
             f"tiled_matmul_reducescatter: leading dim {t} must divide the "
@@ -161,7 +158,7 @@ def ring_matmul_allreduce(x: jax.Array, w: jax.Array, axis_name: str, *,
     so ragged token counts stay exact.
     """
     t = x.shape[0]
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     pad = (-t) % n
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
@@ -229,7 +226,7 @@ def fused_attention_linear(q, k, v, w_o, axis_name: str, *,
 def make_sharded_fused_block(mesh, axis_name: str = "model", **kw):
     """shard_map-wrapped fused_attention_linear over head-sharded inputs."""
     fn = functools.partial(fused_attention_linear, axis_name=axis_name, **kw)
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(None, None, axis_name, None),
                   P(None, None, axis_name, None),

@@ -14,12 +14,14 @@ Level 2 (paper: L1 -> L0, small blocks, Cube/Vector pipelining):
     the Mosaic compiler pipelines across sub-tiles (the Cube/Vector overlap
     of Figure 2).
 
-Tiling-mask (paper §4.1, T2): a single (2M)x(2M) lower-triangular M-mask in
-VMEM generates every B-mask by shifted ``dynamic_slice``; sub-tiles are
-classified SKIP / PARTIAL / FULL.  SKIP blocks are pruned both at the grid
-level (the KV index map clamps to the last valid macro-block, so pruned
-blocks are neither fetched nor computed) and at sub-tile level (pl.when).
-FULL blocks skip the mask add entirely (the Vector-unit saving).
+Tiling-mask (paper §4.1, T2): sub-tiles are classified SKIP / PARTIAL /
+FULL.  SKIP blocks are pruned both at the grid level (the KV index map
+clamps to the last valid macro-block, so pruned blocks are neither fetched
+nor computed) and at sub-tile level (pl.when).  FULL blocks skip the mask
+entirely (the Vector-unit saving).  A PARTIAL sub-tile's B-mask is built
+from iota comparisons of its global row/column positions -- the same mask
+as the paper's shifted M-mask slice (core/tiling_mask.py, the reference
+the tests check), without the unaligned VMEM slice Mosaic cannot lower.
 """
 from __future__ import annotations
 
@@ -31,20 +33,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
-from repro.core import tiling_mask as tm
-
 NEG_INF = -1e30
 LANES = 128
 
 
-def _kernel(q_ref, k_ref, v_ref, mmask_ref, o_ref,
+def _kernel(q_ref, k_ref, v_ref, o_ref,
             acc_ref, m_ref, l_ref, *,
             causal: bool, window: Optional[int], softcap: Optional[float],
             scale: float, q_offset: int, kv_valid: int,
-            block_q: int, block_kv1: int, block_kv2: int,
-            n_kv1: int, mm: int):
+            block_q: int, block_kv1: int, block_kv2: int, n_kv1: int):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -86,7 +83,6 @@ def _kernel(q_ref, k_ref, v_ref, mmask_ref, o_ref,
             if window is not None:
                 skip = skip | (kv_end <= q_start - window)
                 full = full & (kv_start >= q_end - window + 1)
-            pad_tail = kv_valid % block_kv2 != 0 or True
             skip = skip | (kv_start >= kv_valid)
             full = full & (kv_end < kv_valid)
 
@@ -101,22 +97,18 @@ def _kernel(q_ref, k_ref, v_ref, mmask_ref, o_ref,
                     s = softcap * jnp.tanh(s / softcap)
 
                 def _masked(s):
-                    # B-mask = shifted slice(s) of the M-mask (VPU work,
-                    # only on PARTIAL sub-tiles).
-                    bm = tm.slice_bmask(mmask_ref[...], delta,
-                                        block_q, block_kv2)
+                    # B-mask of a PARTIAL sub-tile (VPU work): global
+                    # row/column positions compared arithmetically.
+                    rows = q_start + jax.lax.broadcasted_iota(
+                        jnp.int32, (block_q, block_kv2), 0)
+                    cols = kv_start + jax.lax.broadcasted_iota(
+                        jnp.int32, (block_q, block_kv2), 1)
+                    bm = cols < kv_valid
+                    if causal:
+                        bm = bm & (rows >= cols)
                     if window is not None:
-                        low = tm.slice_bmask(mmask_ref[...], delta - window,
-                                             block_q, block_kv2)
-                        bm = bm * (1 - low)
-                    # KV-padding rows: single-row slice broadcast
-                    # B[r,c] = (kv_valid - kv_start - 1 >= c).
-                    prow = tm.slice_bmask(
-                        mmask_ref[...],
-                        jnp.clip(kv_valid - kv_start - 1, -mm, mm),
-                        1, block_kv2)
-                    bm = bm * prow
-                    return jnp.where(bm != 0, s, NEG_INF)
+                        bm = bm & (rows - cols < window)
+                    return jnp.where(bm, s, NEG_INF)
 
                 s = jax.lax.cond(full, lambda s: s, _masked, s)
 
@@ -168,7 +160,7 @@ def fastattn_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     q: (B, Hq, Sq, D); k, v: (B, Hkv, Skv, D), Hq % Hkv == 0.
     Sequence lengths need not be multiples of the block sizes (padded
-    internally; padding masked through the M-mask row trick).
+    internally; the padded tail is masked like any KV past ``kv_valid``).
     ``kv_valid`` marks only the first rows of K/V as real (a gathered
     paged view whose last page is partially filled); the tail is masked
     exactly like internal padding.
@@ -193,8 +185,6 @@ def fastattn_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     n_q = sq_p // block_q
     n_kv1 = skv_p // block_kv1
-    mm = max(block_q, block_kv2)
-    mmask = tm.make_m_mask(mm, jnp.int8)
 
     grid = (b, hq, n_q, n_kv1)
 
@@ -219,7 +209,7 @@ def fastattn_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kernel = functools.partial(
         _kernel, causal=causal, window=window, softcap=softcap, scale=scale,
         q_offset=q_offset, kv_valid=kv_valid, block_q=block_q,
-        block_kv1=block_kv1, block_kv2=block_kv2, n_kv1=n_kv1, mm=mm)
+        block_kv1=block_kv1, block_kv2=block_kv2, n_kv1=n_kv1)
 
     out = pl.pallas_call(
         kernel,
@@ -228,7 +218,6 @@ def fastattn_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pl.BlockSpec((1, 1, block_q, d), q_map),
             pl.BlockSpec((1, 1, block_kv1, d), kv_map),
             pl.BlockSpec((1, 1, block_kv1, d), kv_map),
-            pl.BlockSpec((2 * mm, 2 * mm), lambda *_: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d), q_map),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq_p, d), q.dtype),
@@ -237,12 +226,12 @@ def fastattn_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, LANES), jnp.float32),  # running max
             pltpu.VMEM((block_q, LANES), jnp.float32),  # running sum
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
         interpret=interpret,
-    )(q, k, v, mmask)
+    )(q, k, v)
     return out[:, :, :sq, :]
 
 
@@ -395,7 +384,7 @@ def paged_prefill_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq_p, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

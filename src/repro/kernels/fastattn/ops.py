@@ -45,6 +45,8 @@ def fastattn(q, k, v,
             scale=scale, q_offset=q_offset, kv_valid=kv_valid,
             block_q=block_q, block_kv1=block_kv1, block_kv2=block_kv2,
             interpret=(impl == "interpret"))
+    if impl != "reference":
+        raise ValueError(f"unknown fastattn impl {impl!r}")
     return _ref.flash_reference(
         q, k, v, causal=causal, window=window, softcap=softcap, scale=scale,
         q_offset=q_offset, kv_len=kv_valid, block_kv=block_kv1)

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 LANES = 128
@@ -147,7 +146,7 @@ def flash_decode_fwd(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g_pad, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(kv_len.astype(jnp.int32), qg, k_cache, v_cache)
@@ -233,7 +232,7 @@ def paged_flash_decode_fwd(q: jax.Array, k_pages: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g_pad, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),

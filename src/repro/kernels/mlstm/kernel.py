@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -165,7 +164,7 @@ def mlstm_chunkwise_fwd(q, k, v, i_gate, f_gate, *, chunk: int = 128,
             pltpu.VMEM((8, dk), jnp.float32),
             pltpu.SMEM((1, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, igc, fgc)

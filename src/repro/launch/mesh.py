@@ -15,14 +15,9 @@ from repro.config import ParallelConfig
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """jax.make_mesh with explicit Auto axis types where the installed
-    jax supports them (jax.sharding.AxisType landed after 0.4.37; older
-    versions are Auto-only, so omitting the kwarg is equivalent)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis explicitly Auto-sharded."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -14,14 +14,15 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Dict, List, NamedTuple, Set
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.config import (ParallelConfig, ServeConfig, get_model_config,
                           reduce_for_smoke)
-from repro.core.offload import OffloadLatencyModel, plan_offload
+from repro.core.offload import plan_offload
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh_for
 from repro.models import build_model
 from repro.serving.core import EngineCore
@@ -29,6 +30,48 @@ from repro.serving.engine import ServeEngine
 from repro.serving.faults import RequestRejected
 from repro.serving.scheduler import SamplingParams
 from repro.sharding.rules import axis_rules
+
+
+def init_params(model, seed: int, sharding=None):
+    """Random weights from ``seed``, made on the device under jit.  The
+    eager ``model.init`` stacks the per-layer trees and briefly holds two
+    copies of the weights; the jitted one writes each leaf once.
+    ``sharding`` places the tree (e.g. replicated over a TP mesh)."""
+    return jax.jit(model.init, out_shardings=sharding)(
+        jax.random.PRNGKey(seed))
+
+
+class StreamResult(NamedTuple):
+    tokens: Dict[int, List[int]]    # request id -> streamed tokens
+    errors: Dict[int, str]          # request id -> error event detail
+    finished: Set[int]              # ids whose terminal event arrived
+    n_events: int                   # non-error events (tokens and stops)
+    seconds: float                  # host wall time of the drain
+
+
+def drain(core: EngineCore, log=print) -> StreamResult:
+    """Step ``core`` until it has no work, collecting every request's
+    stream and logging each request that finishes or fails."""
+    tokens: Dict[int, List[int]] = {}
+    errors: Dict[int, str] = {}
+    finished: Set[int] = set()
+    n_events = 0
+    t0 = time.perf_counter()  # repro-lint: disable=raw-wall-clock (CLI wall time)
+    while core.has_work:
+        for ev in core.step():
+            if ev.finished:
+                finished.add(ev.request_id)
+            if ev.kind == "error":
+                errors[ev.request_id] = ev.detail
+                log(f"req {ev.request_id} failed: {ev.detail}")
+                continue
+            n_events += 1
+            if ev.kind == "token":
+                tokens.setdefault(ev.request_id, []).append(ev.token)
+            if ev.finished:
+                log(f"req {ev.request_id} finished ({ev.index + 1} tokens)")
+    dt = time.perf_counter() - t0  # repro-lint: disable=raw-wall-clock (CLI wall time)
+    return StreamResult(tokens, errors, finished, n_events, dt)
 
 
 def _run_stream(model, params, cfg, args) -> None:
@@ -73,18 +116,8 @@ def _run_stream(model, params, cfg, args) -> None:
             # queue_policy="reject" surfaces a structured error at
             # submission; the engine keeps serving what it admitted
             print(f"rejected: {e.detail}")
-    t0 = time.perf_counter()  # repro-lint: disable=raw-wall-clock (CLI wall time)
-    n_events = 0
-    while core.has_work:
-        for ev in core.step():
-            if ev.kind == "error":
-                print(f"req {ev.request_id} failed: {ev.detail}")
-                continue
-            n_events += 1
-            if ev.finished:
-                print(f"req {ev.request_id} finished "
-                      f"({ev.index + 1} tokens)")
-    dt = time.perf_counter() - t0  # repro-lint: disable=raw-wall-clock (CLI wall time)
+    res = drain(core)
+    n_events, dt = res.n_events, res.seconds
     s = core.stats()
     print(f"{n_events} tokens in {dt:.2f}s ({n_events / dt:.1f} tok/s), "
           f"{s['steps']} engine steps, peak pool "
@@ -171,6 +204,7 @@ def main(argv=None):
                          "write the flight recorder's Chrome trace_event "
                          "JSON there (load in chrome://tracing)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_model_config(args.arch)
     if args.smoke:
@@ -186,7 +220,7 @@ def main(argv=None):
         print("T4 offload plan:", plan.summary())
 
     with axis_rules(mesh=mesh):
-        params = model.init(jax.random.PRNGKey(0))
+        params = init_params(model, 0)
         if args.stream:
             _run_stream(model, params, cfg, args)
             return

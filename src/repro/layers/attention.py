@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig
-from repro.core.compat import shard_map as _shard_map
 from repro.core.distributed_decode import (attend_with_positions,
                                            merge_partial_attention,
                                            paged_local_view,
@@ -420,7 +419,7 @@ def _tp_attention_decode_paged(params, x, cfg: ModelConfig,
         merged = merge_partial_attention(out, lse, seq_ax)
         return _tp_o_proj(merged, wo_loc, si, hq_s, xb.dtype, plan), kp, vp
 
-    out, k, v = _shard_map(
+    out, k, v = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), pool_spec, pool_spec, P(), P()),
         out_specs=(P(), pool_spec, pool_spec),
@@ -471,7 +470,7 @@ def _tp_attention_prefill_paged(params, x, cfg: ModelConfig,
         merged = merge_partial_attention(out, lse, seq_ax)
         return _tp_o_proj(merged, wo_loc, si, hq_s, xb.dtype, plan), kp, vp
 
-    out, k, v = _shard_map(
+    out, k, v = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), pool_spec, pool_spec, P(), P(), P()),
         out_specs=(P(), pool_spec, pool_spec),

@@ -5,7 +5,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import shard_map as _shard_map
 from repro.core.tiled_allreduce import matmul_allreduce
 from repro.layers import common
 from repro.sharding.rules import constrain
@@ -79,5 +78,5 @@ def _tp_apply_mlp(params, x, mlp_type: str, tpc):
                              first_chunk_frac=plan.first_chunk_frac)
         return y.reshape(b, s, -1)
 
-    return _shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                      out_specs=P(), check_vma=False)(params, x)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                         out_specs=P(), check_vma=False)(params, x)

@@ -82,8 +82,10 @@ class LM:
             if reps == 1:
                 params[f"seg{si}"] = init_unit(kseg[0])
             else:
-                params[f"seg{si}"] = common.stack_params(
-                    [init_unit(k) for k in kseg])
+                # vmapped over the layer keys: the same values as
+                # stacking per-layer inits, built stacked (one copy, one
+                # small program instead of one per layer)
+                params[f"seg{si}"] = jax.vmap(init_unit)(kseg)
         return params
 
     def logical(self) -> dict:

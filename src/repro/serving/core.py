@@ -43,6 +43,7 @@ from typing import Dict, List, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.config import ModelConfig, ServeConfig
 from repro.core.fastattention import default_paged_impl
@@ -228,6 +229,11 @@ class EngineCore:
                     f"found {jax.device_count()}")
             self.tp_plan = plan
             self.tp_mesh = make_mesh(plan.mesh_shape, plan.axes)
+            # weights replicated on the mesh once (a no-op when they were
+            # made there): params left on one device would be re-sent to
+            # every shard on every launch
+            self.params = jax.device_put(
+                params, NamedSharding(self.tp_mesh, PartitionSpec()))
         # jitted paged prefill/decode triples keyed by (resolved impl,
         # tp plan); shared with the ServeEngine wrapper so clearing one
         # clears both
@@ -389,6 +395,7 @@ class EngineCore:
             "peak_utilization": mgr.peak_utilization,
             "prefill_launches": self.prefill_launches,
             "prefill_trace_count": self.prefill_trace_count,
+            "paged_impl": self._paged_impl(),
             "orphan_events_pending": len(self.orphan_events),
             "orphans_dropped": self.orphan_events.dropped,
             "pressure": dict(self.pressure.stats),
@@ -608,8 +615,7 @@ class EngineCore:
         traced when a verify step actually launches, so spec_mode="off"
         never pays for it."""
         impl = self._paged_impl()
-        if (impl == "paged" and jax.default_backend() == "tpu"
-                and self.serve.page_size % 128):
+        if impl == "paged" and self.serve.page_size % 128:
             raise ValueError(
                 f"page_size={self.serve.page_size} must be a multiple of "
                 "128 (TPU lane width) for the compiled Pallas paged "

@@ -70,11 +70,8 @@ def _scatter_impl(pools, idx, host_data):
 
 # jitted with the pools donated so XLA updates the pages in place --
 # an eager .at[].set would materialise a full copy of every per-layer
-# pool (the whole KV budget) per restored victim.  Donation is skipped
-# on CPU where it is unsupported (and would only warn).
-_scatter_jit = jax.jit(
-    _scatter_impl,
-    donate_argnums=(0,) if jax.default_backend() != "cpu" else ())
+# pool (the whole KV budget) per restored victim.
+_scatter_jit = jax.jit(_scatter_impl, donate_argnums=(0,))
 
 
 def scatter_pages(pools, pages, host_data):
@@ -98,9 +95,7 @@ def _copy_impl(pools, src, dst):
     return jax.tree.map(cp, pools)
 
 
-_copy_jit = jax.jit(
-    _copy_impl,
-    donate_argnums=(0,) if jax.default_backend() != "cpu" else ())
+_copy_jit = jax.jit(_copy_impl, donate_argnums=(0,))
 
 
 def copy_pages(pools, srcs, dsts):

@@ -10,8 +10,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.compat import axis_size as _axis_size
-
 
 def quantize_int8(x: jax.Array):
     scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-12) / 127.0
@@ -32,7 +30,7 @@ def compressed_psum(grads, residuals, axis_name: str):
 
     Returns (reduced_grads_f32, new_residuals).
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def one(g, r):
         v = g.astype(jnp.float32) + r

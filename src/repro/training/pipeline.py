@@ -19,9 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import axis_size as _axis_size
-from repro.core.compat import shard_map as _shard_map
-
 
 def pipeline_body(stage_params, x_micro, *, stage_fn: Callable,
                   axis: str = "stage"):
@@ -30,7 +27,7 @@ def pipeline_body(stage_params, x_micro, *, stage_fn: Callable,
     stage 0 reads it).  Returns (n_micro, mb, ...) outputs (valid on every
     device after the trailing psum)."""
     idx = jax.lax.axis_index(axis)
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     n_micro = x_micro.shape[0]
     mb_shape = x_micro.shape[1:]
 
@@ -66,7 +63,7 @@ def make_pipeline(mesh, stage_fn: Callable, *, axis: str = "stage",
     body = functools.partial(pipeline_body, stage_fn=lambda p, x:
                              stage_fn(jax.tree.map(lambda a: a[0], p), x),
                              axis=axis)
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: params_spec, params_spec)
                   if not isinstance(params_spec, P) else params_spec,

@@ -29,7 +29,7 @@ import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh
-from repro.core.compat import shard_map
+from jax import shard_map
 """
 
 

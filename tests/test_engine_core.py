@@ -702,22 +702,40 @@ def test_stop_strings_trim_and_span_token_boundary(built):
     serve = ServeConfig(max_batch=3, max_seq_len=96, page_size=16,
                         prefill_chunk=16, debug_invariants=True,
                         num_pages=13)
-    rng = np.random.default_rng(42)
-    prompt = rng.integers(0, cfg.vocab_size, size=7)
     sp = SamplingParams(max_new_tokens=6)
 
-    plain = EngineCore(model, params, cfg, serve, detokenize=_detok)
-    want = _solo_tokens(plain, prompt, sp)
-    assert len(want) == 6
-    # a stop string crossing the boundary between generated tokens 1 and
-    # 2: the tail of piece 1 plus the head of piece 2
-    pieces = [f"<{t}>" for t in want]
-    stop = pieces[1][-2:] + pieces[2][:2]
-    text = "".join(pieces)
-    match = text.find(stop)
-    ends = np.cumsum([len(p) for p in pieces])
-    exp_emitted = int((ends <= match).sum())
-    assert 0 < exp_emitted < 3                    # genuinely mid-stream
+    def boundary_stop(want):
+        """A stop string crossing the boundary between generated tokens
+        k and k+1 (the tail of piece k plus the head of piece k+1): the
+        first boundary whose string first occurs after piece 0, or None
+        -- a greedy stream that repeats one token matches inside piece 0
+        at every boundary, and then nothing would be emitted."""
+        pieces = [f"<{t}>" for t in want]
+        text = "".join(pieces)
+        ends = np.cumsum([len(p) for p in pieces])
+        for k in range(len(pieces) - 1):
+            stop = pieces[k][-2:] + pieces[k + 1][:2]
+            match = text.find(stop)
+            if match >= ends[0]:
+                return stop, int((ends <= match).sum())
+        return None
+
+    # the random smoke model often repeats one token: take the first
+    # prompt whose greedy stream has a usable boundary
+    for seed in range(42, 74):
+        prompt = np.random.default_rng(seed).integers(0, cfg.vocab_size,
+                                                      size=7)
+        plain = EngineCore(model, params, cfg, serve, detokenize=_detok)
+        want = _solo_tokens(plain, prompt, sp)
+        assert len(want) == 6
+        found = boundary_stop(want)
+        if found is not None:
+            break
+    else:
+        pytest.fail("no prompt gave a greedy stream with a boundary stop "
+                    "string after piece 0")
+    stop, exp_emitted = found
+    assert 0 < exp_emitted < len(want)            # genuinely mid-stream
 
     core = EngineCore(model, params, cfg, serve, detokenize=_detok)
     core.add_request(prompt, SamplingParams(max_new_tokens=6,

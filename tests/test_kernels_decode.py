@@ -104,8 +104,8 @@ def test_paged_gather_roundtrip():
 
 
 def test_paged_facade_matches_dense_reference_impl():
-    """fast_attention_decode(impl="paged") == impl="reference" on the
-    same logical cache, ragged GQA batch."""
+    """fast_attention_decode(impl="paged_interpret") == impl="reference"
+    on the same logical cache, ragged GQA batch."""
     b, hq, hkv, s, d, page_size = 3, 8, 2, 384, 64, 128
     rng = np.random.default_rng(4)
     q = jnp.asarray(rng.normal(size=(b, 1, hq, d)), jnp.float32)
@@ -119,7 +119,7 @@ def test_paged_facade_matches_dense_reference_impl():
         jnp.asarray(dense_v.transpose(0, 2, 1, 3), jnp.float32), kv_len,
         impl="reference")
     out = fast_attention_decode(q, k_pages, v_pages, kv_len,
-                                impl="paged", page_table=table)
+                                impl="paged_interpret", page_table=table)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-2, atol=2e-2)
 
