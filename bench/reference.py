@@ -1,0 +1,211 @@
+"""The plain reference: a dense GQA transformer (Phi-3, Qwen2) in float32.
+
+It follows the published architecture, not the program under test, and
+imports nothing of it: token embedding, then per layer RMSNorm, Q/K/V
+projections (with biases where the configuration has them), rotary
+position embedding on the two halves of each head, causal softmax
+attention with each KV head shared by ``Hq / Hkv`` query heads, the
+output projection and a residual, then RMSNorm, a SwiGLU MLP and a
+residual; a final RMSNorm and the untied head.  Weights come from
+``weights.py`` for the same seed, rounded to bfloat16 as they are served,
+and every product runs in float32 at the highest precision.  Layers are
+made and applied one at a time and sequences one at a time, so the
+reference fits beside nothing else on one chip.
+
+``control="int8"`` also computes the same forward with every matrix of
+the layers and the head quantised to int8 per output column (symmetric,
+one scale per column), the next precision below bfloat16 that a program
+could be tempted to serve in; ``control="e4m3"`` does the same in
+float8 e4m3 (each column scaled to the format's largest value, 448).
+"""
+from __future__ import annotations
+
+from typing import Dict, List, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+import weights as W
+
+MATRICES = ("q", "k", "v", "o", "gate", "up", "down")
+HEAD_ROWS = 128
+
+
+def int8_roundtrip(w: jax.Array) -> jax.Array:
+    """Quantise a (in, out) float32 matrix to int8 with one scale per
+    output column and back."""
+    scale = jnp.max(jnp.abs(w), axis=0, keepdims=True) / 127.0
+    scale = jnp.where(scale == 0, 1.0, scale)
+    return jnp.clip(jnp.round(w / scale), -127, 127) * scale
+
+
+def e4m3_round(x: jax.Array) -> jax.Array:
+    """Round float32 values within +-448 to the nearest float8 e4m3 value
+    (ties to even), by arithmetic: 3 mantissa bits down to the exponent
+    -6 and steps of 2**-9 below it.  A cast through ``float8_e4m3fn``
+    does not do this on a TPU v5e: there it changed 70% of the weights
+    by 0.12%, not 99% by 2.6% (PERF.md)."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    exp = ((bits >> 23) & 0xFF) - 127              # x's binary exponent
+    step_exp = jnp.maximum(exp - 3, -9)
+    step = jax.lax.bitcast_convert_type((step_exp + 127) << 23, jnp.float32)
+    return jnp.round(x / step) * step
+
+
+def e4m3_roundtrip(w: jax.Array) -> jax.Array:
+    """Quantise a (in, out) float32 matrix to float8 e4m3 with one scale
+    per output column (its largest magnitude to 448) and back."""
+    scale = jnp.max(jnp.abs(w), axis=0, keepdims=True) / 448.0
+    scale = jnp.where(scale == 0, 1.0, scale)
+    return e4m3_round(w / scale) * scale
+
+
+QUANT = {"int8": int8_roundtrip, "e4m3": e4m3_roundtrip}
+
+
+def _rms(x, scale, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
+
+
+def _rope(x, pos, theta):
+    """x: (S, H, hd); rotate the first and second halves of each head."""
+    hd = x.shape[-1]
+    freqs = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    ang = pos[:, None].astype(jnp.float32) * freqs
+    sin, cos = jnp.sin(ang)[:, None], jnp.cos(ang)[:, None]
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def _block(cfg: dict, w: Dict[str, jax.Array], x, n_valid):
+    """One layer over one padded sequence x: (S, D); rows past
+    ``n_valid`` are padding (causality keeps them out of real rows)."""
+    s, d = x.shape
+    hq, hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    hd = d // hq
+    eps = cfg["rms_norm_eps"]
+    pos = jnp.arange(s)
+    h = _rms(x, w["input_norm"], eps)
+    q, k, v = h @ w["q"], h @ w["k"], h @ w["v"]
+    if "q_bias" in w:
+        q, k, v = q + w["q_bias"], k + w["k_bias"], v + w["v_bias"]
+    q = _rope(q.reshape(s, hq, hd), pos, cfg["rope_theta"])
+    k = _rope(k.reshape(s, hkv, hd), pos, cfg["rope_theta"])
+    v = v.reshape(s, hkv, hd)
+    rep = hq // hkv
+    q = q.reshape(s, hkv, rep, hd)
+    out = []
+    blk = 512 if s % 512 == 0 else s
+    for q0 in range(0, s, blk):       # query blocks bound the score tensor
+        qb = q[q0:q0 + blk]
+        sc = jnp.einsum("qgrd,kgd->grqk", qb, k) / jnp.sqrt(float(hd))
+        causal = (q0 + jnp.arange(qb.shape[0]))[:, None] >= pos[None, :]
+        sc = jnp.where(causal, sc, -jnp.inf)
+        p = jax.nn.softmax(sc, axis=-1)
+        out.append(jnp.einsum("grqk,kgd->qgrd", p, v))
+    a = jnp.concatenate(out, 0).reshape(s, hq * hd)
+    x = x + a @ w["o"]
+    h = _rms(x, w["post_norm"], eps)
+    return x + (jax.nn.silu(h @ w["gate"]) * (h @ w["up"])) @ w["down"]
+
+
+class Reference:
+    """Float32 logits of the configuration for the weights of ``seed``;
+    ``control`` (a key of ``QUANT``) adds the forward with its matrices
+    in that precision beside it."""
+
+    def __init__(self, cfg: dict, seed: int, max_len: int,
+                 control: Optional[str] = None):
+        if control is not None and control not in QUANT:
+            raise ValueError(f"unknown control {control!r}")
+        self.cfg, self.max_len, self.control = cfg, max_len, control
+        self.key = W.base_key(seed)
+        cfg_ = cfg
+        quantise = QUANT.get(control)
+
+        def layer_f32(key, i, quant):
+            w = {n: a.astype(jnp.float32)
+                 for n, a in W.layer(cfg_, key, i).items()}
+            if quant:
+                w.update({n: quantise(w[n]) for n in MATRICES})
+            return w
+
+        self._layer = jax.jit(layer_f32, static_argnums=(2,))
+
+        def block(w, x, n):
+            with jax.default_matmul_precision("highest"):
+                return _block(cfg_, w, x, n)
+
+        self._block = jax.jit(block)
+
+        def head(hw, fn, x, start):
+            # a fixed block of rows from ``start`` (one program for every
+            # request; rows past the sequence are clipped and dropped)
+            rows = jnp.take(x, start + jnp.arange(HEAD_ROWS), axis=0,
+                            mode="clip")
+            with jax.default_matmul_precision("highest"):
+                return _rms(rows, fn, cfg_["rms_norm_eps"]) @ hw
+
+        self._head = jax.jit(head)
+        self._quant = jax.jit(quantise) if quantise else None
+
+    def roundtrip_stats(self) -> Dict[str, float]:
+        """What the control's quantisation does to the served weights, on
+        the device through the control's own programs: the share of the
+        elements of layer 0's matrices and of the head that it changes,
+        and its error (Frobenius norm of the change over the matrix's)."""
+        ws = self._layer(self.key, jnp.int32(0), False)
+        qs = self._layer(self.key, jnp.int32(0), True)
+        pairs = [(ws[n], qs[n]) for n in MATRICES]
+        hw = W.lm_head(self.cfg, self.key).astype(jnp.float32)
+        pairs.append((hw, self._quant(hw)))
+        changed = sum(float(jnp.sum(w != q)) for w, q in pairs)
+        size = sum(w.size for w, _ in pairs)
+        err = np.sqrt(sum(float(jnp.sum((w - q) ** 2)) for w, q in pairs)
+                      / sum(float(jnp.sum(w * w)) for w, _ in pairs))
+        return {"changed_share": changed / size, "rel_error": float(err)}
+
+    def logits(self, seqs: Sequence[Tuple[Sequence[int], int, int]]
+               ) -> List[Dict[str, np.ndarray]]:
+        """For each ``(tokens, first, count)``: the float32 logits rows
+        ``first .. first+count-1`` of ``tokens`` (row t predicts token
+        t+1) under ``"ref"``; with ``control`` also the control forward's
+        rows under its name."""
+        cfg, L = self.cfg, self.cfg["num_hidden_layers"]
+        emb = W.embed(cfg, self.key)
+        streams = {"ref": []}
+        if self.control:
+            streams[self.control] = []
+        for toks, _, _ in seqs:
+            buf = np.zeros((self.max_len,), np.int32)
+            buf[:len(toks)] = toks
+            x = jnp.take(emb, jnp.asarray(buf), axis=0).astype(jnp.float32)
+            for name in streams:
+                streams[name].append(x)
+        del emb
+        for i in range(L):
+            for name, xs in streams.items():
+                w = self._layer(self.key, jnp.int32(i), name != "ref")
+                for j, (toks, _, _) in enumerate(seqs):
+                    xs[j] = self._block(w, xs[j], len(toks))
+                del w
+        hw = W.lm_head(self.cfg, self.key).astype(jnp.float32)
+        fn = W.final_norm(self.cfg).astype(jnp.float32)
+        out = [dict() for _ in seqs]
+        for name, xs in streams.items():
+            w = self._quant(hw) if name != "ref" else hw
+            for j, (_, first, count) in enumerate(seqs):
+                out[j][name] = np.concatenate([
+                    np.asarray(self._head(w, fn, xs[j], jnp.int32(s)))
+                    for s in range(first, first + count, HEAD_ROWS)
+                ])[:count]
+            del w
+        return out
+
+
+def gaps(ref_rows: np.ndarray, tokens: Sequence[int]) -> np.ndarray:
+    """By how much each token's reference logit lies below the
+    reference's best in its row."""
+    picked = ref_rows[np.arange(len(tokens)), np.asarray(tokens)]
+    return ref_rows.max(axis=-1) - picked

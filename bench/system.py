@@ -1,0 +1,179 @@
+"""The system under test: the repository's ``EngineCore``, built for one
+cell.
+
+This is the one module of the benchmark that imports the program (from
+``src/`` of the checkout).  It makes the program's model configuration
+from the configuration file, lays the benchmark's own random weights
+(``weights.py``) into the program's parameter tree in one jitted call,
+sets the engine up from the mix's ``engine`` block (its page pools built
+under jit, ``fill_pools``), and warms every program the cell's traffic
+can launch through the engine's public ``add_request``/``step``.
+"""
+from __future__ import annotations
+
+import os
+import sys
+from typing import Iterable, List
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+import weights as W
+
+SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))), "src")
+if SRC not in sys.path:
+    sys.path.insert(0, SRC)
+
+from repro.config import ModelConfig, ParallelConfig, ServeConfig  # noqa: E402
+from repro.models import build_model  # noqa: E402
+from repro.serving.core import EngineCore  # noqa: E402
+from repro.serving.pressure import copy_pages  # noqa: E402
+from repro.serving.scheduler import SamplingParams  # noqa: E402
+
+# the program's decode and prefill attention kernels, as the trace names
+# their custom calls
+DECODE_KERNEL = "paged_flash_decode_fwd"
+PREFILL_KERNEL = "paged_prefill_fwd"
+
+
+def model_config(cfg: dict) -> ModelConfig:
+    d, hq = cfg["hidden_size"], cfg["num_attention_heads"]
+    return ModelConfig(
+        name=cfg.get("model_type", "dense"), family="dense",
+        num_layers=cfg["num_hidden_layers"], d_model=d, num_heads=hq,
+        num_kv_heads=cfg["num_key_value_heads"], head_dim=d // hq,
+        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
+        block_pattern=("attn",), qkv_bias=bool(cfg.get("qkv_bias")),
+        rope_theta=float(cfg["rope_theta"]), norm_type="rmsnorm",
+        norm_eps=float(cfg["rms_norm_eps"]), mlp_type="swiglu",
+        tie_embeddings=bool(cfg["tie_word_embeddings"]),
+        dtype="bfloat16", param_dtype="bfloat16")
+
+
+# program parameter leaf (below the stacked layer segment) -> weights.py
+LAYER_LEAVES = {("attn", "wq"): "q", ("attn", "wk"): "k", ("attn", "wv"): "v",
+                ("attn", "wo"): "o", ("attn", "bq"): "q_bias",
+                ("attn", "bk"): "k_bias", ("attn", "bv"): "v_bias",
+                ("mlp", "w_gate"): "gate", ("mlp", "w_up"): "up",
+                ("mlp", "w_down"): "down", ("ln1", "scale"): "input_norm",
+                ("ln2", "scale"): "post_norm"}
+
+
+def make_params(model, cfg: dict, seed: int):
+    """The served weights for ``seed`` in the program's tree, made on the
+    device by one jitted call.  Fails if the program's tree has a leaf
+    this mapping does not know, or a shape other than ``weights.py``'s."""
+    want = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    if set(want) != {"embedding", "final_norm", "seg0"} \
+            or set(want["seg0"]) != {"u0"}:
+        raise ValueError(f"unexpected parameter tree {sorted(want)}")
+    L = cfg["num_hidden_layers"]
+
+    def make(key):
+        layers = jax.vmap(lambda i: W.layer(cfg, key, i))(jnp.arange(L))
+        unit = {}
+        for (grp, leaf), name in LAYER_LEAVES.items():
+            if leaf in want["seg0"]["u0"].get(grp, {}):
+                unit.setdefault(grp, {})[leaf] = layers[name]
+        emb = {"embed": W.embed(cfg, key)}
+        if "lm_head" in want["embedding"]:
+            emb["lm_head"] = W.lm_head(cfg, key)
+        return {"embedding": emb, "final_norm": {"scale": W.final_norm(cfg)},
+                "seg0": {"u0": unit}}
+
+    key = W.base_key(seed)     # an argument: one program for every seed
+    got = jax.eval_shape(make, key)
+    if jax.tree.structure(got) != jax.tree.structure(want) or any(
+            a.shape != b.shape or a.dtype != b.dtype
+            for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want))):
+        raise ValueError("weights.py does not fit the program's tree")
+    return jax.jit(make)(key)
+
+
+def serve_config(engine: dict) -> ServeConfig:
+    return ServeConfig(
+        max_batch=engine["max_batch"], max_seq_len=engine["max_seq_len"],
+        page_size=engine["page_size"], num_pages=engine["num_pages"],
+        prefill_chunk=engine["prefill_chunk"],
+        prefix_cache=engine.get("prefix_cache", True),
+        preempt_policy=engine.get("preempt_policy", "auto"),
+        paged_impl=engine.get("paged_impl", "auto"))
+
+
+def fill_pools(core: EngineCore) -> None:
+    """Materialise the page pools under jit.  The engine's own first step
+    builds them eagerly, layer by layer and then stacked, which holds
+    more than two pools at once: beside phi3-mini's weights an 80-page
+    pool (4.03 GB) does not fit that way on a 16 GB chip."""
+    mgr = core.mgr
+    core.pools = jax.jit(lambda: core.model.init_paged_cache(
+        mgr.num_pages, mgr.page_size))()
+
+
+def build(cfg: dict, engine: dict, seed: int):
+    mcfg = model_config(cfg)
+    model = build_model(mcfg, ParallelConfig())
+    params = make_params(model, cfg, seed)
+    core = EngineCore(model, params, mcfg, serve_config(engine))
+    fill_pools(core)
+    return core
+
+
+def prefill_widths(max_batch: int) -> List[int]:
+    """Launch widths the chunked prefill pads to: powers of two up to
+    ``max_batch``."""
+    w, out = 1, []
+    while w < max_batch:
+        out.append(w)
+        w *= 2
+    return out + [max_batch]
+
+
+def _serve(core: EngineCore, prompts, samplings) -> None:
+    for prompt, sp in zip(prompts, samplings):
+        core.add_request(prompt, sp)
+    while core.has_work:
+        core.step()
+
+
+def warm(core: EngineCore, samplings: Iterable[dict], cow_pairs: int = 0,
+         max_width: int = 0) -> None:
+    """Run every program the window can launch once, through the
+    engine's own ``add_request``/``step``: for each prefill width a wave
+    of that many requests whose prompts fit one chunk together, so they
+    prefill in one launch of that width and then decode at ``max_batch``
+    (the widest wave also holds one request of each sampling of the mix,
+    so the mixed-sampling decode path and the sampled first token run).
+    Prompts are shorter than a page, so the prefix cache keeps nothing of
+    them.  Then copy-on-write page copies of 1..``cow_pairs`` pairs on the
+    scratch page."""
+    serve, mgr = core.serve, core.mgr
+    b, chunk = serve.max_batch, serve.prefill_chunk_tokens
+    widths = prefill_widths(min(b, max_width or b))
+    plen = max(1, min(serve.page_size - 1, chunk // widths[-1]))
+    rng = np.random.default_rng(0)
+    sampled = [SamplingParams(temperature=s["temperature"], top_k=s["top_k"],
+                              seed=1, max_new_tokens=3) for s in samplings]
+    for w in widths:
+        sps = [SamplingParams(max_new_tokens=3) for _ in range(w)]
+        if w == widths[-1] and w > 1:
+            sps[1:1 + len(sampled)] = sampled[:w - 1]
+        before = core.prefill_launches
+        _serve(core, [rng.integers(0, core.cfg.vocab_size, plen,
+                                   dtype=np.int32) for _ in range(w)], sps)
+        if core.prefill_launches - before != 1:
+            raise RuntimeError(
+                f"warm-up: a wave of {w} prompts made "
+                f"{core.prefill_launches - before} prefill launches, not "
+                "one of that width")
+    for n in range(1, cow_pairs + 1):
+        core.pools = copy_pages(core.pools, [mgr.SCRATCH] * n,
+                                [mgr.SCRATCH] * n)
+    jax.block_until_ready(core.pools)
+
+
+def peak_bytes(devices) -> int:
+    return max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+               for d in devices)
