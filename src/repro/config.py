@@ -329,11 +329,16 @@ class ServeConfig:
 
     # --- telemetry (serving/metrics.py) ---------------------------------
     # Master switch for the engine telemetry subsystem: per-step phase
-    # timings, per-request lifecycle spans (TTFT/TPOT/queue-delay
+    # durations on the engine clock (host time in each bracketed phase,
+    # including any wait for the device when the phase syncs; never
+    # device time), per-request lifecycle spans (TTFT/TPOT/queue-delay
     # histograms) and the step flight recorder.  The registry itself
-    # (counters backing ``stats()``) always runs -- it is a handful of
-    # integer adds per step; this gates the clock reads.  All telemetry
-    # is host-side only and can never change jit trace counts.
+    # (counters backing ``stats()``) and the ``engine.<phase>`` profiler
+    # spans always run -- integer adds and about a microsecond per span
+    # with the profiler off; under ``jax.profiler`` those spans land on
+    # the device trace's clock, where device time per phase is read.
+    # This gates the clock reads.  All telemetry is host-side only and
+    # can never change jit trace counts.
     metrics: bool = True
     # Ring-buffer depth of the step flight recorder: how many recent
     # step records survive for an ``EngineError``/quarantine postmortem

@@ -51,7 +51,7 @@ from repro.serving.faults import (EngineError, InjectedFault, LogitError,
                                   RequestError, RequestRejected,
                                   RequestTimeout, SwapRestoreFailed)
 from repro.serving.metrics import (FlightRecorder, LifecycleTracer,
-                                   MetricsRegistry)
+                                   MetricsRegistry, StepPhases)
 from repro.serving.paged_cache import OutOfPages, PagedKVCache
 from repro.serving.prefix_cache import RadixPrefixIndex
 from repro.serving.pressure import PressureManager, copy_pages
@@ -151,11 +151,14 @@ class EngineCore:
         self._clock = clock or time.monotonic
         # -- telemetry (serving/metrics.py) ----------------------------
         # The registry is always live: its counters back the ``stats()``
-        # view (a handful of integer adds per step).  The lifecycle
-        # tracer, per-step phase breakdown and flight recorder gate on
-        # ``serve.metrics`` -- they are the clock-read overhead.  All of
-        # it is host-side between launches: trace-neutral by
-        # construction, asserted in tests/test_metrics.py.
+        # view (a handful of integer adds per step).  The step-phase
+        # profiler spans (``engine.<phase>``, ``self.phases``) are always
+        # opened too, at about a microsecond each with the profiler off.
+        # The lifecycle tracer, the phases' engine-clock durations and
+        # the flight recorder gate on ``serve.metrics`` -- they are the
+        # clock-read overhead.  All of it is host-side between launches:
+        # trace-neutral by construction, asserted in
+        # tests/test_metrics.py.
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         m = self.metrics
         self._c_steps = m.counter("engine_steps_total",
@@ -192,6 +195,18 @@ class EngineCore:
             buckets=tuple(float(i)
                           for i in range(self.serve.spec_tokens + 1)),
             help="accepted draft run length per verify step")
+        # each launch's work, as the engine.prefill / engine.decode spans
+        # carry it in their args
+        self._c_decode_rows = m.counter(
+            "engine_decode_rows_total",
+            help="sequences in decode launches (one token each)")
+        self._c_decode_launches = m.counter(
+            "engine_decode_launches_total", help="decode launches")
+        self._c_prefill_tokens = m.counter(
+            "engine_prefill_tokens_total",
+            help="prompt tokens in prefill launches")
+        self._c_prefill_launches = m.counter(
+            "engine_prefill_launches_total", help="prefill launches")
         self._h_step = m.histogram("engine_step_seconds",
                                    help="step() wall-clock on the "
                                         "engine clock")
@@ -200,6 +215,7 @@ class EngineCore:
         self._g_pages_hw = m.gauge("kv_pages_peak", high_water=True,
                                    help="peak KV pages in use "
                                         "(current window)")
+        self.phases = StepPhases(self._clock)
         self.tracer = (LifecycleTracer(m, self._clock)
                        if self.serve.metrics else None)
         self.flight = (FlightRecorder(self.serve.flight_recorder_steps)
@@ -722,22 +738,26 @@ class EngineCore:
         """Sample a freshly-prefilled sequence's first token and flip
         the request into the decoding state.  Sampling faults (injected,
         non-finite logits) quarantine this request only."""
+        phase = self.phases
         try:
             self._fire("sample")
-            self._guard_logits(req, last_logits)
-            tok = self._sample(req, last_logits)
+            with phase("guard"):
+                self._guard_logits(req, last_logits)
+            with phase("sample"):
+                tok = self._sample(req, last_logits)
         except (InjectedFault, RequestError) as e:
             self._quarantine(req, e, events)
             return
-        req.state = RUNNING
-        req.generated.append(tok)
-        self.next_tok[slot] = tok
-        if self.tracer is not None:
-            # first-token opens the running span; on_token counts it so
-            # TPOT sees the same token stream the bench does
-            self.tracer.on_first_token(req)
-            self.tracer.on_token(req)
-        self._stream(req, events)
+        with phase("stream"):
+            req.state = RUNNING
+            req.generated.append(tok)
+            self.next_tok[slot] = tok
+            if self.tracer is not None:
+                # first-token opens the running span; on_token counts it
+                # so TPOT sees the same token stream the bench does
+                self.tracer.on_first_token(req)
+                self.tracer.on_token(req)
+            self._stream(req, events)
 
     # ------------------------------------------------------------------
     # event emission (stop-string holdback)
@@ -842,13 +862,9 @@ class EngineCore:
             # and every other pair stays owed for the next _apply_cow
             mgr.cow_pending = pairs
             raise
-        t0 = self._clock() if self._step_rec is not None else 0.0
-        self.pools = copy_pages(self.pools, [s for s, _ in pairs],
-                                [d for _, d in pairs])
-        if self._step_rec is not None:
-            ph = self._step_rec["phases"]
-            ph["cow_replay"] = ph.get("cow_replay", 0.0) \
-                + (self._clock() - t0)
+        with self.phases("cow_replay"):
+            self.pools = copy_pages(self.pools, [s for s, _ in pairs],
+                                    [d for _, d in pairs])
 
     def _grow(self, slot: int, n: int) -> None:
         """``mgr.append(slot, n)`` with page-pressure relief: on
@@ -918,7 +934,9 @@ class EngineCore:
             }
         err: Optional[EngineError] = None
         try:
-            events = self._step()
+            with self.phases.step(None if self._step_rec is None
+                                  else self._step_rec["phases"]):
+                events = self._step()
             if self._step_rec is not None:
                 self._step_rec["events"] = len(events)
             return events
@@ -958,47 +976,31 @@ class EngineCore:
             return events
         self._c_steps.inc()
         rec = self._step_rec
-        if rec is not None:
-            # phase marks: elapsed engine-clock time since the previous
-            # mark (cow_replay is accounted inside _apply_cow and may
-            # overlap the prefill/decode phases that triggered it)
-            clock = self._clock
-            last_t = [clock()]
-
-            def mark(phase: str) -> None:
-                t = clock()
-                ph = rec["phases"]
-                ph[phase] = ph.get(phase, 0.0) + (t - last_t[0])
-                last_t[0] = t
-        else:
-            def mark(phase: str) -> None:
-                pass
+        phase = self.phases
         ps = mgr.page_size
         self._ensure_pools()
         pre_scan, pre_chunk, decode, verify = self._paged_fns()
 
-        # ---- deadline sweep ------------------------------------------
-        # before admission, so an already-expired waiting request never
-        # takes a slot; expired running requests are quarantined cleanly
-        # (pages freed, stash dropped) with a structured timeout event
-        now = self._clock()
-        expired = [r for r in list(sched.waiting) + list(sched.resuming)
-                   if r.deadline_expired(now)]
-        expired += [r for _, r in sched.running()
-                    if r.deadline_expired(now) and not r.done]
-        for req in expired:
-            self._quarantine(req, RequestTimeout(
-                f"request {req.id}: deadline "
-                f"{req.sampling.deadline_ms:g}ms exceeded",
-                request_id=req.id), events)
-        mark("deadline_sweep")
-
-        for req in sched.retire():
-            self.requests.pop(req.id, None)
-            if self.spec is not None:
-                self.spec.forget(req.id)
-        admitted = sched.admit()
-        mark("schedule")
+        with phase("schedule"):
+            # deadline sweep before admission, so an already-expired
+            # waiting request never takes a slot; expired running
+            # requests are quarantined cleanly (pages freed, stash
+            # dropped) with a structured timeout event
+            now = self._clock()
+            expired = [r for r in list(sched.waiting)
+                       + list(sched.resuming) if r.deadline_expired(now)]
+            expired += [r for _, r in sched.running()
+                        if r.deadline_expired(now) and not r.done]
+            for req in expired:
+                self._quarantine(req, RequestTimeout(
+                    f"request {req.id}: deadline "
+                    f"{req.sampling.deadline_ms:g}ms exceeded",
+                    request_id=req.id), events)
+            for req in sched.retire():
+                self.requests.pop(req.id, None)
+                if self.spec is not None:
+                    self.spec.forget(req.id)
+            admitted = sched.admit()
         # RESUMING path: swap-preempted requests re-admitted by the
         # scheduler get their stashed KV copied back into the pages
         # admission just materialised (their shared prefix was re-shared
@@ -1006,28 +1008,28 @@ class EngineCore:
         # rejoins the decode batch directly (its next input token was
         # sampled before the preemption).  A stash whose resume was
         # downgraded to recompute is dropped.
-        for slot, req in admitted:
-            if self.pressure.holds(req.id):
-                if req.resume_kind == "swap":
-                    try:
-                        self.pools = self.pressure.restore(
-                            self.pools, slot, req)
-                    except SwapRestoreFailed:
-                        # H2D failed past its retry budget: downgrade
-                        # the resume to recompute -- unwind the slot,
-                        # drop the stash, requeue.  Strictly slower,
-                        # never a failed request.
+        with phase("swap"):
+            for slot, req in admitted:
+                if self.pressure.holds(req.id):
+                    if req.resume_kind == "swap":
+                        try:
+                            self.pools = self.pressure.restore(
+                                self.pools, slot, req)
+                        except SwapRestoreFailed:
+                            # H2D failed past its retry budget: downgrade
+                            # the resume to recompute -- unwind the slot,
+                            # drop the stash, requeue.  Strictly slower,
+                            # never a failed request.
+                            self.pressure.drop(req.id)
+                            self.pressure._bump("swap_fail_downgrades")
+                            req.resume_kind = "recompute"
+                            req.resume_shared_len = 0
+                            sched.preempt(slot)
+                            continue
+                    else:
                         self.pressure.drop(req.id)
-                        self.pressure._bump("swap_fail_downgrades")
-                        req.resume_kind = "recompute"
-                        req.resume_shared_len = 0
-                        sched.preempt(slot)
-                        continue
-                else:
-                    self.pressure.drop(req.id)
-            if req.state == RUNNING:
-                self.next_tok[slot] = req.generated[-1]
-        mark("swap_restore")
+                if req.state == RUNNING:
+                    self.next_tok[slot] = req.generated[-1]
         if rec is not None:
             rec["waiting"] = len(sched.waiting)
             rec["resuming"] = len(sched.resuming)
@@ -1073,17 +1075,24 @@ class EngineCore:
                 start = req.prefilled
                 toks = req.prefill_tokens[start:]
                 try:
-                    self._grow(slot, len(toks))
+                    with phase("grow"):
+                        self._grow(slot, len(toks))
                 except InjectedFault as e:
                     self._quarantine(req, e, events)
                     continue
-                # repro-lint: disable=retrace-hazard (the scan
-                # oracle deliberately traces per prompt length; the
-                # production path is the chunked paged prefill)
-                self.pools, last_logits = pre_scan(
-                    self.params, jnp.asarray(toks[None]), self.pools,
-                    jnp.asarray(mgr.device_row(slot)),
-                    jnp.full((1,), start, jnp.int32))
+                with phase("prefill") as span:
+                    n = len(toks)
+                    span.args(rows=1, tokens=n,
+                              attended=n * start + n * (n + 1) // 2)
+                    self._c_prefill_launches.inc()
+                    self._c_prefill_tokens.inc(n)
+                    # repro-lint: disable=retrace-hazard (the scan
+                    # oracle deliberately traces per prompt length; the
+                    # production path is the chunked paged prefill)
+                    self.pools, last_logits = pre_scan(
+                        self.params, jnp.asarray(toks[None]), self.pools,
+                        jnp.asarray(mgr.device_row(slot)),
+                        jnp.full((1,), start, jnp.int32))
                 req.prefilled = start + len(toks)
                 if req.generated:
                     self._resume_decode(req, slot)
@@ -1106,40 +1115,49 @@ class EngineCore:
                 except InjectedFault:
                     break
                 live = []
-                for slot, req, start, n in group:
-                    if sched.slots[slot] is not req \
-                            or req.state != PREFILLING:
-                        continue        # victim of an earlier _grow
-                    try:
-                        self._grow(slot, n)
-                    except InjectedFault as e:
-                        self._quarantine(req, e, events)
-                        continue
-                    live.append((slot, req, start, n))
+                with phase("grow"):
+                    for slot, req, start, n in group:
+                        if sched.slots[slot] is not req \
+                                or req.state != PREFILLING:
+                            continue    # victim of an earlier _grow
+                        try:
+                            self._grow(slot, n)
+                        except InjectedFault as e:
+                            self._quarantine(req, e, events)
+                            continue
+                        live.append((slot, req, start, n))
                 # _grow may have evicted an earlier group member
                 live = [(s, r, st, n) for s, r, st, n in live
                         if sched.slots[s] is r]
                 if not live:
                     continue
-                bw = 1
-                while bw < len(live):
-                    bw *= 2
-                bw = min(bw, width)
-                buf = np.zeros((bw, chunk), np.int32)
-                table = np.full((bw, mgr.max_pages_per_seq),
-                                mgr.SCRATCH, np.int32)
-                pos0 = np.zeros((bw,), np.int32)
-                nval = np.zeros((bw,), np.int32)
-                for i, (slot, req, start, n) in enumerate(live):
-                    buf[i, :n] = req.prefill_tokens[start:start + n]
-                    table[i] = mgr.table[slot]
-                    pos0[i] = start
-                    nval[i] = n
-                self.prefill_launches += 1
-                self.pools, last_logits = pre_chunk(
-                    self.params, jnp.asarray(buf), self.pools,
-                    jnp.asarray(table), jnp.asarray(pos0),
-                    jnp.asarray(nval))
+                with phase("prefill") as span:
+                    bw = 1
+                    while bw < len(live):
+                        bw *= 2
+                    bw = min(bw, width)
+                    buf = np.zeros((bw, chunk), np.int32)
+                    table = np.full((bw, mgr.max_pages_per_seq),
+                                    mgr.SCRATCH, np.int32)
+                    pos0 = np.zeros((bw,), np.int32)
+                    nval = np.zeros((bw,), np.int32)
+                    tokens = attended = 0
+                    for i, (slot, req, start, n) in enumerate(live):
+                        buf[i, :n] = req.prefill_tokens[start:start + n]
+                        table[i] = mgr.table[slot]
+                        pos0[i] = start
+                        nval[i] = n
+                        tokens += n
+                        attended += n * start + n * (n + 1) // 2
+                    span.args(rows=len(live), tokens=tokens,
+                              attended=attended)
+                    self.prefill_launches += 1
+                    self._c_prefill_launches.inc()
+                    self._c_prefill_tokens.inc(tokens)
+                    self.pools, last_logits = pre_chunk(
+                        self.params, jnp.asarray(buf), self.pools,
+                        jnp.asarray(table), jnp.asarray(pos0),
+                        jnp.asarray(nval))
                 for i, (slot, req, start, n) in enumerate(live):
                     req.prefilled = start + n
                     if not req.prefill_done:
@@ -1149,8 +1167,6 @@ class EngineCore:
                     else:
                         self._first_token(req, slot,
                                           last_logits[i:i + 1], events)
-
-        mark("prefill")
 
         # ---- decode phase --------------------------------------------
         cand = [(s, r) for s, r in sched.decoding() if not r.done]
@@ -1165,84 +1181,92 @@ class EngineCore:
             # one-token decode launch.  ``spec is None`` keeps the plain
             # path below byte-for-byte (greedy output is bit-identical
             # either way; only the launch count differs).
-            self._spec_decode(cand, events, mark, verify)
+            self._spec_decode(cand, events, verify)
             self._c_events.inc(len(events))
             return events
         # materialise the page (maybe a fresh one) every running
         # sequence's next token will be written to -- evicting other
         # sequences under pressure -- THEN snapshot the table for the
         # device step.
-        for slot, req in cand:
-            if sched.slots[slot] is not req:
-                continue                # evicted by an earlier _grow
-            try:
-                self._grow(slot, 1)
-            except InjectedFault as e:
-                self._quarantine(req, e, events)
+        with phase("grow"):
+            for slot, req in cand:
+                if sched.slots[slot] is not req:
+                    continue            # evicted by an earlier _grow
+                try:
+                    self._grow(slot, 1)
+                except InjectedFault as e:
+                    self._quarantine(req, e, events)
         running = [(s, r) for s, r in cand if sched.slots[s] is r]
         if serve.debug_invariants:
             self._check_invariants()
         if not running:
-            mark("decode")
             self._c_events.inc(len(events))
             return events
-        pos_np = np.zeros((serve.max_batch,), np.int32)
-        for slot, _ in running:
-            pos_np[slot] = mgr.seq_len(slot) - 1
-        table = mgr.device_table()
-        for slot, _ in sched.prefilling():
-            # mid-prefill slots sit out the decode step: scratch-page
-            # table row + pos 0, like idle slots (their real pages must
-            # not see the decode step's writes)
-            table[slot, :] = mgr.SCRATCH
-        logits, self.pools = decode(
-            self.params, jnp.asarray(self.next_tok), self.pools,
-            jnp.asarray(table), jnp.asarray(pos_np))
-        mark("decode")
+        with phase("decode") as span:
+            pos_np = np.zeros((serve.max_batch,), np.int32)
+            ctx_tokens = 0
+            for slot, _ in running:
+                n = mgr.seq_len(slot)   # positions attended, new included
+                pos_np[slot] = n - 1
+                ctx_tokens += n
+            span.args(rows=len(running), ctx_tokens=ctx_tokens)
+            self._c_decode_launches.inc()
+            self._c_decode_rows.inc(len(running))
+            table = mgr.device_table()
+            for slot, _ in sched.prefilling():
+                # mid-prefill slots sit out the decode step: scratch-page
+                # table row + pos 0, like idle slots (their real pages
+                # must not see the decode step's writes)
+                table[slot, :] = mgr.SCRATCH
+            logits, self.pools = decode(
+                self.params, jnp.asarray(self.next_tok), self.pools,
+                jnp.asarray(table), jnp.asarray(pos_np))
         rowok = None
         if serve.logit_guard == "fail":
-            # one device-side reduction + a max_batch-bool transfer: the
-            # guard never pulls the full logits to host
-            rowok = np.asarray(jnp.all(jnp.isfinite(logits), axis=-1))
-        if all(r.sampling.greedy for _, r in running):
-            # one batched argmax: the common all-greedy step costs one
-            # device op, and matches the pre-core engine bit for bit
-            toks = np.asarray(jnp.argmax(logits, axis=-1)
-                              .astype(jnp.int32))
-            picked = {slot: int(toks[slot]) for slot, _ in running}
-        else:
-            # mixed sampling: one host sync, then per-row eager sampling
-            # -- O(batch) small dispatches per step, acceptable at the
-            # decode batch widths served here; a batched vmapped sampler
-            # keyed on (temperature, top_k) groups is the upgrade path
-            logits_np = np.asarray(logits)
-            picked = {slot: self._sample(req, logits_np[slot])
-                      for slot, req in running}
-        mark("sample")
-        for slot, req in running:
-            try:
-                self._fire("sample")
-                if rowok is not None and not rowok[slot]:
-                    raise LogitError(
-                        f"request {req.id}: non-finite logits at token "
-                        f"{len(req.generated)}", request_id=req.id)
-            except (InjectedFault, RequestError) as e:
-                self._quarantine(req, e, events)
-                continue
-            tok = picked[slot]
-            req.generated.append(tok)
-            self.next_tok[slot] = tok
-            if self.tracer is not None:
-                self.tracer.on_token(req)
-            self._stream(req, events)
-        mark("detok")
+            with phase("guard"):
+                # one device-side reduction + a max_batch-bool transfer:
+                # the guard never pulls the full logits to host
+                rowok = np.asarray(jnp.all(jnp.isfinite(logits), axis=-1))
+        with phase("sample"):
+            if all(r.sampling.greedy for _, r in running):
+                # one batched argmax: the common all-greedy step costs one
+                # device op, and matches the pre-core engine bit for bit
+                toks = np.asarray(jnp.argmax(logits, axis=-1)
+                                  .astype(jnp.int32))
+                picked = {slot: int(toks[slot]) for slot, _ in running}
+            else:
+                # mixed sampling: one host sync, then per-row eager
+                # sampling -- O(batch) small dispatches per step; a
+                # batched vmapped sampler keyed on (temperature, top_k)
+                # groups is the upgrade path
+                logits_np = np.asarray(logits)
+                picked = {slot: self._sample(req, logits_np[slot])
+                          for slot, req in running}
+        with phase("stream"):
+            for slot, req in running:
+                try:
+                    self._fire("sample")
+                    if rowok is not None and not rowok[slot]:
+                        raise LogitError(
+                            f"request {req.id}: non-finite logits at "
+                            f"token {len(req.generated)}",
+                            request_id=req.id)
+                except (InjectedFault, RequestError) as e:
+                    self._quarantine(req, e, events)
+                    continue
+                tok = picked[slot]
+                req.generated.append(tok)
+                self.next_tok[slot] = tok
+                if self.tracer is not None:
+                    self.tracer.on_token(req)
+                self._stream(req, events)
         self._c_events.inc(len(events))
         return events
 
     # ------------------------------------------------------------------
     # speculative decode phase (serving/spec.py)
     # ------------------------------------------------------------------
-    def _spec_decode(self, cand, events: List[StreamEvent], mark,
+    def _spec_decode(self, cand, events: List[StreamEvent],
                      verify) -> None:
         """One speculative step for every running slot: draft up to K
         continuation tokens from the request's own text, append them to
@@ -1256,107 +1280,112 @@ class EngineCore:
         drafting; an injected fault degrades the step to K=0 (a
         one-token verify -- same tokens, strictly no speculation)."""
         sched, mgr, serve = self.sched, self.mgr, self.serve
+        phase = self.phases
         width = serve.spec_tokens + 1
-        try:
-            self._fire("spec_verify")
-            drafts = {}
-            for slot, req in cand:
-                # cap K so a fully-accepted run plus its bonus token
-                # never overshoots max_new_tokens (also bounds page
-                # growth to what submit-time validation admitted)
-                cap = min(serve.spec_tokens,
-                          req.max_new_tokens - len(req.generated) - 1)
-                drafts[slot] = (self.spec.propose(req)[:cap]
-                                if cap > 0 else [])
-        except InjectedFault:
-            drafts = {slot: [] for slot, _ in cand}
-        for slot, req in cand:
-            if sched.slots[slot] is not req:
-                continue                # evicted by an earlier _grow
+        with phase("verify"):
             try:
-                self._grow(slot, 1 + len(drafts[slot]))
-            except InjectedFault as e:
-                self._quarantine(req, e, events)
-        running = [(s, r) for s, r in cand if sched.slots[s] is r]
-        if serve.debug_invariants:
-            self._check_invariants()
-        if not running:
-            mark("verify")
-            return
-        # slot-indexed batch like the decode step, but every row NOT in
-        # the verify batch gets a scratch table row: with n_valid=0 all
-        # its K/V writes land in the scratch page, so prefilling, done
-        # and idle slots never see this launch
-        buf = np.zeros((serve.max_batch, width), np.int32)
-        table = np.full((serve.max_batch, mgr.max_pages_per_seq),
-                        mgr.SCRATCH, np.int32)
-        pos0 = np.zeros((serve.max_batch,), np.int32)
-        nval = np.zeros((serve.max_batch,), np.int32)
-        for slot, req in running:
-            d = drafts[slot]
-            buf[slot, 0] = self.next_tok[slot]
-            if d:
-                buf[slot, 1:1 + len(d)] = d
-            table[slot] = mgr.table[slot]
-            pos0[slot] = mgr.seq_len(slot) - (1 + len(d))
-            nval[slot] = 1 + len(d)
-        self.spec_launches += 1
-        self.pools, logits = verify(
-            self.params, jnp.asarray(buf), self.pools,
-            jnp.asarray(table), jnp.asarray(pos0), jnp.asarray(nval))
-        mark("verify")
+                self._fire("spec_verify")
+                drafts = {}
+                for slot, req in cand:
+                    # cap K so a fully-accepted run plus its bonus token
+                    # never overshoots max_new_tokens (also bounds page
+                    # growth to what submit-time validation admitted)
+                    cap = min(serve.spec_tokens,
+                              req.max_new_tokens - len(req.generated) - 1)
+                    drafts[slot] = (self.spec.propose(req)[:cap]
+                                    if cap > 0 else [])
+            except InjectedFault:
+                drafts = {slot: [] for slot, _ in cand}
+            with phase("grow"):
+                for slot, req in cand:
+                    if sched.slots[slot] is not req:
+                        continue        # evicted by an earlier _grow
+                    try:
+                        self._grow(slot, 1 + len(drafts[slot]))
+                    except InjectedFault as e:
+                        self._quarantine(req, e, events)
+            running = [(s, r) for s, r in cand if sched.slots[s] is r]
+            if serve.debug_invariants:
+                self._check_invariants()
+            if not running:
+                return
+            # slot-indexed batch like the decode step, but every row NOT
+            # in the verify batch gets a scratch table row: with
+            # n_valid=0 all its K/V writes land in the scratch page, so
+            # prefilling, done and idle slots never see this launch
+            buf = np.zeros((serve.max_batch, width), np.int32)
+            table = np.full((serve.max_batch, mgr.max_pages_per_seq),
+                            mgr.SCRATCH, np.int32)
+            pos0 = np.zeros((serve.max_batch,), np.int32)
+            nval = np.zeros((serve.max_batch,), np.int32)
+            for slot, req in running:
+                d = drafts[slot]
+                buf[slot, 0] = self.next_tok[slot]
+                if d:
+                    buf[slot, 1:1 + len(d)] = d
+                table[slot] = mgr.table[slot]
+                pos0[slot] = mgr.seq_len(slot) - (1 + len(d))
+                nval[slot] = 1 + len(d)
+            self.spec_launches += 1
+            self.pools, logits = verify(
+                self.params, jnp.asarray(buf), self.pools,
+                jnp.asarray(table), jnp.asarray(pos0), jnp.asarray(nval))
         rowok = None
         if serve.logit_guard == "fail":
-            # (B, width) bools: acceptance guards each row only when its
-            # logits are consumed, so K=0 matches the plain path exactly
-            rowok = np.asarray(jnp.all(jnp.isfinite(logits), axis=-1))
-        argm = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
-        logits_np = (np.asarray(logits)
-                     if any(not r.sampling.greedy for _, r in running)
-                     else None)
+            with phase("guard"):
+                # (B, width) bools: acceptance guards each row only when
+                # its logits are consumed, so K=0 matches the plain path
+                # exactly
+                rowok = np.asarray(jnp.all(jnp.isfinite(logits), axis=-1))
         survivors = []
-        for slot, req in running:
-            d = drafts[slot]
-            sp = req.sampling
-            old_len = int(pos0[slot])
-            ok_row = rowok[slot] if rowok is not None else None
-            try:
-                self._fire("sample")
-                if sp.greedy:
-                    toks, acc = verify_greedy(
-                        d, argm[slot], stop_ids=req.stop_token_ids,
-                        budget=req.max_new_tokens - len(req.generated),
-                        row_ok=ok_row, request_id=req.id,
-                        n0=len(req.generated))
-                else:
-                    toks, acc = verify_residual(
-                        d, logits_np[slot], seed=sp.seed,
-                        n0=len(req.generated),
-                        temperature=sp.temperature, top_k=sp.top_k,
-                        stop_ids=req.stop_token_ids,
-                        budget=req.max_new_tokens - len(req.generated),
-                        row_ok=ok_row, request_id=req.id)
-            except (InjectedFault, RequestError) as e:
-                self._quarantine(req, e, events)
-                continue
-            if d:
-                self.spec.observe(req.id, len(d), acc)
-                self._c_spec_drafted.inc(len(d))
-                self._c_spec_accepted.inc(acc)
-                self._h_spec_accept.observe(acc / len(d))
-                self._h_spec_run.observe(float(acc))
-            for tok in toks:
-                req.generated.append(int(tok))
-                if self.tracer is not None:
-                    self.tracer.on_token(req)
-            self.next_tok[slot] = req.generated[-1]
-            # exact rollback: drop the rejected drafts' KV rows and the
-            # (never-written) row grown for the newest sampled token
-            mgr.truncate(slot, old_len + len(toks))
-            survivors.append((slot, req))
+        with phase("sample"):
+            argm = np.asarray(jnp.argmax(logits, axis=-1)
+                              .astype(jnp.int32))
+            logits_np = (np.asarray(logits)
+                         if any(not r.sampling.greedy for _, r in running)
+                         else None)
+            for slot, req in running:
+                d = drafts[slot]
+                sp = req.sampling
+                old_len = int(pos0[slot])
+                ok_row = rowok[slot] if rowok is not None else None
+                try:
+                    self._fire("sample")
+                    if sp.greedy:
+                        toks, acc = verify_greedy(
+                            d, argm[slot], stop_ids=req.stop_token_ids,
+                            budget=req.max_new_tokens - len(req.generated),
+                            row_ok=ok_row, request_id=req.id,
+                            n0=len(req.generated))
+                    else:
+                        toks, acc = verify_residual(
+                            d, logits_np[slot], seed=sp.seed,
+                            n0=len(req.generated),
+                            temperature=sp.temperature, top_k=sp.top_k,
+                            stop_ids=req.stop_token_ids,
+                            budget=req.max_new_tokens - len(req.generated),
+                            row_ok=ok_row, request_id=req.id)
+                except (InjectedFault, RequestError) as e:
+                    self._quarantine(req, e, events)
+                    continue
+                if d:
+                    self.spec.observe(req.id, len(d), acc)
+                    self._c_spec_drafted.inc(len(d))
+                    self._c_spec_accepted.inc(acc)
+                    self._h_spec_accept.observe(acc / len(d))
+                    self._h_spec_run.observe(float(acc))
+                for tok in toks:
+                    req.generated.append(int(tok))
+                    if self.tracer is not None:
+                        self.tracer.on_token(req)
+                self.next_tok[slot] = req.generated[-1]
+                # exact rollback: drop the rejected drafts' KV rows and
+                # the (never-written) row grown for the newest sampled
+                # token
+                mgr.truncate(slot, old_len + len(toks))
+                survivors.append((slot, req))
         if serve.debug_invariants:
             self._check_invariants()
-        mark("sample")
-        for slot, req in survivors:
-            self._stream(req, events)
-        mark("detok")
+        with phase("stream"):
+            for slot, req in survivors:
+                self._stream(req, events)

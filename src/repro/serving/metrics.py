@@ -1,4 +1,5 @@
-"""Engine telemetry: metrics registry, lifecycle tracing, flight recorder.
+"""Engine telemetry: metrics registry, lifecycle tracing, step phases,
+flight recorder.
 
 Production serving engines treat observability as a subsystem, not a
 stats dict: vLLM exports Prometheus counters and per-request latency
@@ -7,8 +8,8 @@ its scheduler, and the source FastAttention paper motivates both its
 tiling-AllReduce and its CPU-GPU cooperative strategy with exactly the
 per-phase time/bandwidth breakdowns an uninstrumented engine cannot
 produce.  This module is that subsystem for the EngineCore stack --
-dependency-free, host-side only (nothing here is ever traced by jit, so
-telemetry can never change trace counts), and O(1) on the hot path:
+host-side only (nothing here is ever traced by jit, so telemetry can
+never change trace counts), and O(1) on the hot path:
 
 * :class:`MetricsRegistry` -- named :class:`Counter`/:class:`Gauge`/
   fixed-bucket :class:`Histogram` metrics with *windowed* reads:
@@ -27,20 +28,34 @@ telemetry can never change trace counts), and O(1) on the hot path:
   chaos soak; ``completed`` keeps a bounded log of per-request latency
   records for exact engine-vs-bench comparisons.
 
+* :class:`StepPhases` -- bracketed step-phase spans (``engine.step``
+  around ``engine.schedule``, ``engine.decode``, ``engine.sample``, ...)
+  on two clocks at once: a ``jax.profiler.TraceAnnotation`` on the
+  profiler's host plane, the clock of the device's ``XLA Modules`` /
+  ``XLA Ops`` events, so a device trace puts every idle gap down to the
+  engine phase the host was in; and the engine clock, summed per phase
+  into the flight record and ``engine_phase_<phase>_seconds``.  A
+  phase's duration is host time, not device time (see the class).
+
 * :class:`FlightRecorder` -- a bounded ring buffer of per-step records
   (phase timings, batch composition, pages used, faults fired) the
   engine dumps on ``EngineError``/quarantine and exports as a Chrome
   ``trace_event`` JSON timeline (chrome://tracing / Perfetto) for
   postmortems.
+
+Only :class:`StepPhases` needs jax, and imports ``jax.profiler`` when it
+is built: the rest of the module imports without it.
 """
 from __future__ import annotations
 
+import contextlib
 from bisect import bisect_left
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "LifecycleTracer", "FlightRecorder", "DEFAULT_TIME_BUCKETS"]
+           "LifecycleTracer", "StepPhases", "FlightRecorder",
+           "DEFAULT_TIME_BUCKETS"]
 
 # Upper bucket bounds (seconds, ``le``-inclusive like Prometheus) for
 # the latency histograms: 100us .. 60s, roughly log-spaced.  Chosen to
@@ -456,6 +471,88 @@ class LifecycleTracer:
 
     def on_abort(self, req) -> None:
         self._finish(req, "aborted")
+
+
+# ---------------------------------------------------------------------------
+# step phases: profiler spans + engine-clock durations
+# ---------------------------------------------------------------------------
+
+class StepPhases:
+    """Bracketed phase spans of one engine step.
+
+    ``with phases("decode") as span:`` around a phase's code does two
+    things:
+
+    * opens ``jax.profiler.TraceAnnotation("engine.decode")`` -- a span
+      on the profiler's host plane, on the clock of the device's
+      ``XLA Modules``/``XLA Ops`` events, so a trace shows which phase
+      the host was in during every device-idle gap.  ``span.args(...)``
+      attaches integer args to it (a launch's rows and tokens).  With the
+      profiler off a span costs about a microsecond.
+    * inside :meth:`step` with a phase dict (the flight record's, i.e.
+      telemetry on), adds the region's engine-clock duration to
+      ``times[phase]``; the engine observes each step's sums into
+      ``engine_phase_<phase>_seconds``.  Telemetry off reads no clock.
+
+    What a phase's duration means: the host seconds between entering and
+    leaving the region, nothing else.  Launches are asynchronous, so a
+    launch phase (``prefill``, ``decode``, ``verify``, ``cow_replay``)
+    holds only the host's packing, copies and enqueue; a phase that syncs
+    with the device (``guard``, ``sample``, and ``swap``/``grow`` when
+    pages move to or from the host) also holds the wait for every earlier
+    launch still running.  Device time per phase comes from the device
+    trace, never from these durations.  Phases nest (``cow_replay``
+    inside ``grow``, ``grow`` inside ``verify``); a nested phase's time
+    is also in its parent's.
+    """
+
+    def __init__(self, clock):
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
+        self.clock = clock
+        self.times: Optional[Dict[str, float]] = None
+
+    def __call__(self, phase: str) -> "_PhaseSpan":
+        return _PhaseSpan(self, phase, self.times)
+
+    @contextlib.contextmanager
+    def step(self, times: Optional[Dict[str, float]]):
+        """The outer ``engine.step`` span; the phases opened inside it add
+        their durations to ``times`` (None: no clock reads)."""
+        self.times = times
+        try:
+            with self._annotation("engine.step"):
+                yield
+        finally:
+            self.times = None
+
+
+class _PhaseSpan:
+    __slots__ = ("_owner", "_phase", "_times", "_ann", "_t0")
+
+    def __init__(self, owner: StepPhases, phase: str,
+                 times: Optional[Dict[str, float]]):
+        self._owner = owner
+        self._phase = phase
+        self._times = times
+
+    def __enter__(self) -> "_PhaseSpan":
+        self._ann = self._owner._annotation("engine." + self._phase)
+        self._ann.__enter__()
+        if self._times is not None:
+            self._t0 = self._owner.clock()
+        return self
+
+    def args(self, **kw: int) -> None:
+        """Integer args on the profiler span (the trace event's stats)."""
+        self._ann.set_metadata(**kw)
+
+    def __exit__(self, *exc) -> None:
+        times = self._times
+        if times is not None:
+            times[self._phase] = times.get(self._phase, 0.0) \
+                + (self._owner.clock() - self._t0)
+        self._ann.__exit__(*exc)
 
 
 # ---------------------------------------------------------------------------

@@ -228,23 +228,36 @@ def test_frozen_clock_zeroes_every_engine_duration(built):
     even though real wall time passes.  Any code path still calling
     time.perf_counter()/time.monotonic() directly would mix real
     timestamps into the arithmetic and blow these sums up."""
-    core, cfg = _core(built, clock=lambda: 1000.0)
+    core, cfg = _core(built, clock=lambda: 1000.0, prefix_cache=True)
     rng = np.random.default_rng(3)
     for i in range(4):
         core.add_request(rng.integers(0, cfg.vocab_size, size=5 + 7 * i),
                          SamplingParams(max_new_tokens=4), request_id=i)
     toks = _drain(core)
     assert len(toks) == 4
+    # a page-aligned prompt served twice: the second hits the prefix
+    # cache in full and copies its shared tail page (cow_replay)
+    prompt = rng.integers(0, cfg.vocab_size, size=32)
+    for i in (4, 5):
+        core.add_request(prompt, SamplingParams(max_new_tokens=2),
+                         request_id=i)
+        _drain(core, toks)
+    assert len(toks) == 6
     assert core.step_s_high_water == 0.0
     m = core.metrics
     h_step = m["engine_step_seconds"]
     assert h_step.count == core.steps > 0 and h_step.sum == 0.0
-    for name in m.names():
-        if name.startswith("engine_phase_"):
-            assert m[name].sum == 0.0, f"{name} saw a non-clock duration"
+    phases = {name[len("engine_phase_"):-len("_seconds")]
+              for name in m.names() if name.startswith("engine_phase_")}
+    assert phases == {"schedule", "swap", "grow", "cow_replay", "prefill",
+                      "decode", "guard", "sample", "stream"}
+    for phase in phases:
+        h = m[f"engine_phase_{phase}_seconds"]
+        assert h.count > 0 and h.sum == 0.0, \
+            f"{phase} saw a non-clock duration"
     for rec in core.tracer.completed:
         assert rec["first_token_t"] == rec["submit_t"] == rec["end_t"]
-    assert m["engine_ttft_seconds"].count == 4
+    assert m["engine_ttft_seconds"].count == 6
     assert m["engine_ttft_seconds"].sum == 0.0
     assert m["engine_e2e_seconds"].sum == 0.0
     # flight records carry the frozen timeline too
