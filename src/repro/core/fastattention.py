@@ -45,7 +45,8 @@ def default_paged_impl() -> str:
 
 def fast_attention_prefill_paged(q: jax.Array, k_pages: jax.Array,
                                  v_pages: jax.Array, page_table: jax.Array,
-                                 pos_start: jax.Array, kv_len: jax.Array, *,
+                                 pos_start: jax.Array, kv_len: jax.Array,
+                                 layer=0, *,
                                  window: Optional[int] = None,
                                  softcap: Optional[float] = None,
                                  scale: Optional[float] = None,
@@ -55,7 +56,8 @@ def fast_attention_prefill_paged(q: jax.Array, k_pages: jax.Array,
     KV pools (the chunk's own K/V rows must already be scattered in).
 
     q: (B, Sq, Hq, D) layer-layout chunk queries; pages
-    (Hkv, P, page_size, D); page_table (B, n_kv) int32; pos_start /
+    (Hkv, P, page_size, D), or layer-stacked (L, Hkv, P, page_size, D)
+    read in place at ``layer``; page_table (B, n_kv) int32; pos_start /
     kv_len: (B,) int32 *runtime* offsets -- one jit trace serves every
     chunk position of every prompt length.  "paged" runs the compiled
     Pallas kernel (scalar-prefetched page table; TPU only),
@@ -67,12 +69,12 @@ def fast_attention_prefill_paged(q: jax.Array, k_pages: jax.Array,
     if impl == "paged_reference":
         from repro.kernels.flash_decode.ref import paged_prefill_reference
         out = paged_prefill_reference(
-            qT, k_pages, v_pages, page_table, pos_start, kv_len,
+            qT, k_pages, v_pages, page_table, pos_start, kv_len, layer,
             window=window, softcap=softcap, scale=scale)
     elif impl in ("paged", "paged_interpret"):
         from repro.kernels.fastattn.ops import fastattn_paged_prefill
         out = fastattn_paged_prefill(
-            qT, k_pages, v_pages, page_table, pos_start, kv_len,
+            qT, k_pages, v_pages, page_table, pos_start, kv_len, layer,
             window=window, softcap=softcap, scale=scale, block_q=block_q,
             interpret=impl == "paged_interpret")
     else:
@@ -88,8 +90,8 @@ def fast_attention_decode(q: jax.Array, k_cache: jax.Array,
                           impl: str = "reference",
                           block_kv: int = 512,
                           layout: str = "bshd",
-                          page_table: Optional[jax.Array] = None
-                          ) -> jax.Array:
+                          page_table: Optional[jax.Array] = None,
+                          layer=0) -> jax.Array:
     """Single-token decode attention.
 
     q: (B, 1, Hq, D); caches (B, S, Hkv, D) ["bshd"] or (B, Hkv, S, D)
@@ -98,7 +100,8 @@ def fast_attention_decode(q: jax.Array, k_cache: jax.Array,
 
     With ``impl in ("paged", "paged_interpret", "paged_reference")`` the
     caches are instead global page pools (Hkv, P, page_size, D) shared by
-    every sequence, and ``page_table`` (B, n_kv) int32 maps each
+    every sequence (or layer-stacked (L, Hkv, P, page_size, D) pools read
+    in place at ``layer``), and ``page_table`` (B, n_kv) int32 maps each
     sequence's logical KV block to its physical page (serving/paged_cache
     owns the table).  "paged" runs the compiled Pallas kernel (TPU
     only), "paged_interpret" the same kernel in the Pallas interpreter;
@@ -119,12 +122,12 @@ def fast_attention_decode(q: jax.Array, k_cache: jax.Array,
             from repro.kernels.flash_decode.ref import paged_decode_reference
             out = paged_decode_reference(
                 q.transpose(0, 2, 1, 3), k_cache, v_cache, page_table,
-                kv_len, window=window, softcap=softcap, scale=scale)
+                kv_len, layer, window=window, softcap=softcap, scale=scale)
             return out.transpose(0, 2, 1, 3)
         from repro.kernels.flash_decode.ops import paged_flash_decode
         out = paged_flash_decode(
             q.transpose(0, 2, 1, 3)[:, :, 0], k_cache, v_cache, page_table,
-            kv_len, window=window, softcap=softcap, scale=scale,
+            kv_len, layer, window=window, softcap=softcap, scale=scale,
             interpret=impl == "paged_interpret")[:, :, None]
         return out.transpose(0, 2, 1, 3)
 

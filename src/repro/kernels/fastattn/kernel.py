@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.flash_decode.kernel import kernel_pools
+
 NEG_INF = -1e30
 LANES = 128
 
@@ -239,11 +241,11 @@ def fastattn_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # Paged chunked prefill: a block of prompt tokens against the KV page pools
 # ---------------------------------------------------------------------------
 
-def _paged_prefill_kernel(pt_ref, start_ref, len_ref, q_ref, k_ref, v_ref,
-                          o_ref, acc_ref, m_ref, l_ref, *,
+def _paged_prefill_kernel(pt_ref, start_ref, len_ref, layer_ref, q_ref,
+                          k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                           window: Optional[int], softcap: Optional[float],
                           scale: float, block_q: int, page_size: int,
-                          n_kv: int):
+                          n_kv: int, pages_t: bool):
     bi = pl.program_id(0)
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -267,9 +269,9 @@ def _paged_prefill_kernel(pt_ref, start_ref, len_ref, q_ref, k_ref, v_ref,
     @pl.when((ki >= first_valid) & (ki <= last_valid))
     def _compute():
         q = q_ref[0, 0]                        # (block_q, d)
-        k = k_ref[0, 0]                        # (page_size, d)
+        k = k_ref[0, 0]            # (page_size, d), or (d, page_size)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((1,), (0 if pages_t else 1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
@@ -294,7 +296,8 @@ def _paged_prefill_kernel(pt_ref, start_ref, len_ref, q_ref, k_ref, v_ref,
             jnp.sum(p, axis=1, keepdims=True), m_prev.shape)
         pv = jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0, 0],
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            (((1,), (1 if pages_t else 0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * alpha[:, :1] + pv
         m_ref[...] = m_new
 
@@ -310,7 +313,7 @@ def _paged_prefill_kernel(pt_ref, start_ref, len_ref, q_ref, k_ref, v_ref,
     static_argnames=("window", "softcap", "scale", "block_q", "interpret"))
 def paged_prefill_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                       page_table: jax.Array, pos_start: jax.Array,
-                      kv_len: jax.Array, *,
+                      kv_len: jax.Array, layer=0, *,
                       window: Optional[int] = None,
                       softcap: Optional[float] = None,
                       scale: Optional[float] = None,
@@ -319,19 +322,25 @@ def paged_prefill_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """Causal prefill of one prompt chunk against the paged KV pools.
 
     q: (B, Hq, Sq, D) -- the chunk's queries, already RoPE'd at their
-    global positions; pages: (Hkv, P, page_size, D) global pools (the
-    chunk's K/V rows must already be scattered in); page_table: (B, n_kv)
-    int32; pos_start: (B,) int32 global position of the chunk's first
-    token; kv_len: (B,) int32 valid KV length (= pos_start + valid chunk
-    tokens).  All offsets are runtime values fed through scalar prefetch,
-    so one trace serves every chunk of every prompt: the KV BlockSpec
-    index map resolves logical block ki -> page_table[b, ki] and clamps
-    to the causally-valid page range of the chunk (the grid-level
-    tiling-mask skip of the dense kernel, with dynamic bounds).
+    global positions; pages: (Hkv, P, page_size, D) global pools, or a
+    layer-stacked (L, Hkv, P, page_size, D) pair read in place at
+    ``layer`` (a traced int32 scalar) -- the chunk's K/V rows must
+    already be written in; page_table: (B, n_kv) int32; pos_start: (B,)
+    int32 global position of the chunk's first token; kv_len: (B,) int32
+    valid KV length (= pos_start + valid chunk tokens).  All offsets are
+    runtime values fed through scalar prefetch, so one trace serves
+    every chunk of every prompt: the KV BlockSpec index map resolves
+    logical block ki -> page_table[b, ki] and clamps to the
+    causally-valid page range of the chunk (the grid-level tiling-mask
+    skip of the dense kernel, with dynamic bounds).
     Returns (B, Hq, Sq, D).
     """
+    k_pages, v_pages, layer, pages_t = kernel_pools(k_pages, v_pages, layer)
     b, hq, sq, d = q.shape
-    hkv, _, page_size, _ = k_pages.shape
+    hkv = k_pages.shape[1]
+    page_size = k_pages.shape[3 + pages_t]
+    kv_block = (None, 1, 1) + ((d, page_size) if pages_t
+                               else (page_size, d))
     n_kv = page_table.shape[1]
     assert hq % hkv == 0, (hq, hkv)
     n_rep = hq // hkv
@@ -343,10 +352,10 @@ def paged_prefill_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         q = jnp.pad(q, ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))
     n_q = sq_p // block_q
 
-    def q_map(bi, hi, qi, ki, pt_ref, start_ref, len_ref):
+    def q_map(bi, hi, qi, ki, pt_ref, start_ref, len_ref, layer_ref):
         return (bi, hi, qi, 0)
 
-    def kv_map(bi, hi, qi, ki, pt_ref, start_ref, len_ref):
+    def kv_map(bi, hi, qi, ki, pt_ref, start_ref, len_ref, layer_ref):
         # clamp pruned logical blocks onto the nearest valid one so the
         # pipeline re-uses the resident page instead of DMAing a new one
         q_end = start_ref[bi] + (qi + 1) * block_q - 1
@@ -360,21 +369,24 @@ def paged_prefill_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         # fully-padded q blocks of the last chunk can push `first` (and
         # thus kj) past the table width -- clamp so the scalar-prefetch
         # read stays in bounds (their rows are masked in the kernel)
-        return (hi // n_rep, pt_ref[bi, jnp.minimum(kj, n_kv - 1)], 0, 0)
+        return (layer_ref[0], hi // n_rep,
+                pt_ref[bi, jnp.minimum(kj, n_kv - 1)], 0, 0)
 
     kernel = functools.partial(
         _paged_prefill_kernel, window=window, softcap=softcap, scale=scale,
-        block_q=block_q, page_size=page_size, n_kv=n_kv)
+        block_q=block_q, page_size=page_size, n_kv=n_kv, pages_t=pages_t)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(b, hq, n_q, n_kv),
             in_specs=[
                 pl.BlockSpec((1, 1, block_q, d), q_map),
-                pl.BlockSpec((1, 1, page_size, d), kv_map),
-                pl.BlockSpec((1, 1, page_size, d), kv_map),
+                # one page of one head of one layer; the layer dim is
+                # squeezed out of the kernel's view
+                pl.BlockSpec(kv_block, kv_map),
+                pl.BlockSpec(kv_block, kv_map),
             ],
             out_specs=pl.BlockSpec((1, 1, block_q, d), q_map),
             scratch_shapes=[
@@ -389,5 +401,5 @@ def paged_prefill_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                                  "arbitrary")),
         interpret=interpret,
     )(page_table.astype(jnp.int32), pos_start.astype(jnp.int32),
-      kv_len.astype(jnp.int32), q, k_pages, v_pages)
+      kv_len.astype(jnp.int32), layer, q, k_pages, v_pages)
     return out[:, :, :sq, :]

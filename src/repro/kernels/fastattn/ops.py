@@ -80,18 +80,19 @@ fastattn.defvjp(_fwd, _bwd)
 
 
 def fastattn_paged_prefill(q, k_pages, v_pages, page_table, pos_start,
-                           kv_len, *,
+                           kv_len, layer=0, *,
                            window: Optional[int] = None,
                            softcap: Optional[float] = None,
                            scale: Optional[float] = None,
                            block_q: int = 256,
                            interpret: bool = False):
     """Chunked-prefill attention against the paged KV pools (no vjp --
-    serving only).  q: (B, Hq, Sq, D); pages (Hkv, P, page_size, D);
+    serving only).  q: (B, Hq, Sq, D); pages (Hkv, P, page_size, D) or
+    layer-stacked (L, Hkv, P, page_size, D) read at ``layer``;
     page_table (B, n_kv) int32; pos_start/kv_len (B,) int32 runtime
     offsets (scalar-prefetched: one trace per chunk *shape*, not per
     chunk position)."""
     return _kernel.paged_prefill_fwd(
-        q, k_pages, v_pages, page_table, pos_start, kv_len,
+        q, k_pages, v_pages, page_table, pos_start, kv_len, layer,
         window=window, softcap=softcap, scale=scale, block_q=block_q,
         interpret=interpret)

@@ -19,13 +19,14 @@ def flash_decode(q, k_cache, v_cache, kv_len, *,
         scale=scale, block_kv=block_kv, interpret=interpret)
 
 
-def paged_flash_decode(q, k_pages, v_pages, page_table, kv_len, *,
+def paged_flash_decode(q, k_pages, v_pages, page_table, kv_len, layer=0, *,
                        window: Optional[int] = None,
                        softcap: Optional[float] = None,
                        scale: Optional[float] = None,
                        interpret: bool = False):
     """Decode attention over a paged cache: q (B, Hq, D), pages
-    (Hkv, P, page_size, D), page_table (B, n_kv) int32."""
+    (Hkv, P, page_size, D) or layer-stacked (L, Hkv, P, page_size, D)
+    read at ``layer``, page_table (B, n_kv) int32."""
     return paged_flash_decode_fwd(
-        q, k_pages, v_pages, page_table, kv_len, window=window,
+        q, k_pages, v_pages, page_table, kv_len, layer, window=window,
         softcap=softcap, scale=scale, interpret=interpret)

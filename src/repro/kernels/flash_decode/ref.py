@@ -2,7 +2,9 @@
 
 ``paged_gather`` materialises the dense (B, Hkv, S, D) view of a paged
 pool; ``paged_decode_reference`` chains it with the dense decode oracle so
-paged kernels have an f32-softmax reference on any backend.
+paged kernels have an f32-softmax reference on any backend.  Both take
+the kernels' arguments: one-layer (Hkv, P, page_size, D) pools, or
+layer-stacked (L, Hkv, P, page_size, D) pools read at ``layer``.
 ``paged_prefill_reference`` is the chunked-prefill analogue: gather +
 online-softmax flash with runtime per-sequence query offsets (the
 jittable CPU path of the paged prefill kernel).
@@ -27,13 +29,20 @@ def paged_gather(pages: jax.Array, page_table: jax.Array) -> jax.Array:
     return g.transpose(1, 0, 2, 3, 4).reshape(b, hkv, n_kv * ps, d)
 
 
+def _layer_pools(k_pages, v_pages, layer):
+    if k_pages.ndim == 5:
+        return k_pages[layer], v_pages[layer]
+    return k_pages, v_pages
+
+
 def paged_decode_reference(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, page_table: jax.Array,
-                           kv_len: jax.Array, *,
+                           kv_len: jax.Array, layer=0, *,
                            window: Optional[int] = None,
                            softcap: Optional[float] = None,
                            scale: Optional[float] = None) -> jax.Array:
     """q: (B, Hq, 1, D) against paged pools.  Returns (B, Hq, 1, D)."""
+    k_pages, v_pages = _layer_pools(k_pages, v_pages, layer)
     k = paged_gather(k_pages, page_table)
     v = paged_gather(v_pages, page_table)
     return decode_reference(q, k, v, kv_len, window=window, softcap=softcap,
@@ -42,7 +51,8 @@ def paged_decode_reference(q: jax.Array, k_pages: jax.Array,
 
 def paged_prefill_reference(q: jax.Array, k_pages: jax.Array,
                             v_pages: jax.Array, page_table: jax.Array,
-                            pos_start: jax.Array, kv_len: jax.Array, *,
+                            pos_start: jax.Array, kv_len: jax.Array,
+                            layer=0, *,
                             window: Optional[int] = None,
                             softcap: Optional[float] = None,
                             scale: Optional[float] = None,
@@ -56,6 +66,7 @@ def paged_prefill_reference(q: jax.Array, k_pages: jax.Array,
     Returns (B, Hq, Sq, D); rows past the valid chunk length are garbage
     and must be ignored by the caller.
     """
+    k_pages, v_pages = _layer_pools(k_pages, v_pages, layer)
     k = paged_gather(k_pages, page_table)
     v = paged_gather(v_pages, page_table)
     out, _ = flash_reference_with_lse(

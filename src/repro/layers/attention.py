@@ -26,6 +26,7 @@ from repro.core.fastattention import (default_paged_impl, fast_attention,
                                       fast_attention_decode,
                                       fast_attention_prefill_paged)
 from repro.core.tiled_allreduce import matmul_allreduce
+from repro.kernels.flash_decode.kernel import pages_transposed
 from repro.layers import common, rotary
 from repro.sharding.rules import constrain
 from repro.sharding.tp import current_tp
@@ -198,34 +199,72 @@ def init_kv_pages(cfg: ModelConfig, num_pages: int, page_size: int,
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
-def scatter_kv_pages(cache: KVCache, k_new, v_new, page_table, positions,
-                     n_valid) -> KVCache:
-    """Scatter a chunk of new K/V rows into the paged pools.
+def write_kv_chunk(cache: KVCache, k_new, v_new, page_table, pos_start,
+                   n_valid, layer=0, *, shard=0, shards: int = 1) -> KVCache:
+    """Write a chunk of new K/V rows into the paged pools, in place.
 
-    k_new/v_new: (B, S, Hkv, D); positions: (B, S) int32 global token
-    positions; n_valid: (B,) int32 -- rows past it (chunk padding) are
-    redirected into the scratch page so fixed-size chunks never touch
-    pages owned by live sequences.  The pages covering the valid
-    positions must already be materialised in ``page_table``.
+    k_new/v_new: (B, S, Hkv, D), row i of sequence b at global position
+    ``pos_start[b] + i``; rows ``i >= n_valid[b]`` (chunk padding) are
+    not written.  The pools are one layer's (Hkv, P, ps, D) pair, or a
+    layer-stacked (L, Hkv, P, ps, D) pair written at ``layer``.  The
+    pages covering the valid positions must already be materialised in
+    ``page_table``.
+
+    Each page the chunk touches is read whole, the new rows are merged
+    in, and the pages go back by one scatter whose indices are all arrays
+    (layer, head, page) and whose window is a whole page in the layout
+    the TPU keeps the pool in (``pages_transposed``), so XLA applies it
+    to the donated pool in place.  A row-sized window makes XLA relayout
+    the whole pool where pages are stored transposed, and a scatter of
+    single elements is slower than the copy it saves.  A touched page
+    with no valid row resolves to the scratch page 0 and goes back as it
+    was read.
+
+    With ``shards > 1`` the pools hold page rows ``[shard * ps_local,
+    (shard + 1) * ps_local)`` of every page (a tensor-parallel page-row
+    sub-shard; ``shard`` may be traced): a shard writes the rows it
+    holds.
     """
-    hkv, npages, ps, d = cache.k.shape
-    b, s = positions.shape
-    page = page_table[jnp.arange(b)[:, None], positions // ps]   # (B, S)
-    flat = page * ps + positions % ps
-    valid = jnp.arange(s, dtype=jnp.int32)[None] < n_valid[:, None]
-    flat = jnp.where(valid, flat, 0)          # padding -> scratch page 0
-    # (B, S, Hkv, D) -> (Hkv, B, S, D) rows scattered at flat [b, s]
-    k = cache.k.reshape(hkv, npages * ps, d).at[:, flat].set(
-        k_new.astype(cache.k.dtype).transpose(2, 0, 1, 3))
-    v = cache.v.reshape(hkv, npages * ps, d).at[:, flat].set(
-        v_new.astype(cache.v.dtype).transpose(2, 0, 1, 3))
-    return KVCache(k.reshape(hkv, npages, ps, d),
-                   v.reshape(hkv, npages, ps, d))
+    hkv, psl, d = cache.k.shape[-4], cache.k.shape[-2], cache.k.shape[-1]
+    ps = psl * shards
+    b, s = k_new.shape[:2]
+    n_touch = (s + ps - 2) // ps + 1      # pages a run of s positions spans
+    start = pos_start.astype(jnp.int32)
+    block = start[:, None] // ps + jnp.arange(n_touch, dtype=jnp.int32)
+    # the chunk row each local row of each touched page takes: (B, n, psl)
+    row = (block[:, :, None] * ps + shard * psl - start[:, None, None]
+           + jnp.arange(psl, dtype=jnp.int32))
+    valid = (row >= 0) & (row < n_valid.astype(jnp.int32)[:, None, None])
+    page = page_table[jnp.arange(b)[:, None],
+                      jnp.minimum(block, page_table.shape[1] - 1)]
+    page = jnp.where(valid.any(-1), page, 0)            # (B, n)
+    shape = page.shape + (hkv,)
+    idx = (jax.lax.broadcasted_iota(jnp.int32, shape, 2),
+           jnp.broadcast_to(page[..., None], shape))
+    if cache.k.ndim == 5:
+        idx = (jnp.full(shape, layer, jnp.int32),) + idx
+    transposed = pages_transposed(psl, d)
+    src = jnp.clip(row, 0, s - 1)
+    keep = valid[:, :, None, :, None]                   # (B, n, 1, psl, 1)
+
+    def write(pool, rows):
+        # (B, n, psl, Hkv, D) -> the pages' rows (B, n, Hkv, psl, D)
+        new = jnp.moveaxis(
+            rows.astype(pool.dtype)[jnp.arange(b)[:, None, None], src], 3, 2)
+        mask = keep
+        if transposed:
+            pool, new, mask = (jnp.swapaxes(a, -1, -2)
+                               for a in (pool, new, mask))
+        pool = pool.at[idx].set(jnp.where(mask, new, pool[idx]))
+        return jnp.swapaxes(pool, -1, -2) if transposed else pool
+
+    return KVCache(write(cache.k, k_new), write(cache.v, v_new))
 
 
 def apply_attention_prefill_paged(params, x, cfg: ModelConfig,
                                   cache: KVCache, *, page_table, pos_start,
-                                  n_valid, window: Optional[int] = None,
+                                  n_valid, layer=0,
+                                  window: Optional[int] = None,
                                   impl: Optional[str] = None):
     """Chunked prefill against paged KV pools: one prompt chunk through
     full (not per-token) attention.
@@ -233,10 +272,12 @@ def apply_attention_prefill_paged(params, x, cfg: ModelConfig,
     x: (B, S_chunk, D) -- a fixed-size chunk, possibly padded past
     ``n_valid``; pos_start: (B,) int32 global position of each sequence's
     chunk start; page_table: (B, n_kv) int32.  The chunk's K/V rows are
-    scattered into their pages (padding rows into scratch), then the
+    written into their pages (padding rows are not), then the
     chunk attends to every cached position <= its own through the page
     table.  All offsets are runtime values: one jit trace serves every
-    chunk of every prompt.  Returns (out (B, S_chunk, D), new pools);
+    chunk of every prompt.  The pools are one layer's (Hkv, P, ps, D)
+    pair, or a layer-stacked (L, Hkv, P, ps, D) pair written and read in
+    place at ``layer``.  Returns (out (B, S_chunk, D), new pools);
     output rows past ``n_valid`` are garbage and must be ignored.
 
     Under an active tensor-parallel context (sharding/tp.py) the pools
@@ -247,7 +288,8 @@ def apply_attention_prefill_paged(params, x, cfg: ModelConfig,
     if tpc is not None:
         return _tp_attention_prefill_paged(
             params, x, cfg, cache, page_table=page_table,
-            pos_start=pos_start, n_valid=n_valid, window=window, tpc=tpc)
+            pos_start=pos_start, n_valid=n_valid, layer=layer,
+            window=window, tpc=tpc)
     impl = impl or default_paged_impl()
     b, s, _ = x.shape
     positions = pos_start.astype(jnp.int32)[:, None] + \
@@ -256,11 +298,11 @@ def apply_attention_prefill_paged(params, x, cfg: ModelConfig,
     if cfg.rope_type == "mrope":   # text continuation: t=h=w=pos
         rope_pos = jnp.broadcast_to(positions, (3, b, s))
     q, k_new, v_new = _project_qkv(params, x, cfg, rope_pos)
-    cache = scatter_kv_pages(cache, k_new, v_new, page_table, positions,
-                             n_valid)
+    cache = write_kv_chunk(cache, k_new, v_new, page_table, pos_start,
+                           n_valid, layer)
     kv_len = pos_start.astype(jnp.int32) + n_valid.astype(jnp.int32)
     out = fast_attention_prefill_paged(
-        q, cache.k, cache.v, page_table, pos_start, kv_len,
+        q, cache.k, cache.v, page_table, pos_start, kv_len, layer,
         window=window, softcap=cfg.attn_logit_softcap, impl=impl)
     out = out.reshape(b, s, cfg.q_dim)
     return common.dense(out, params["wo"]), cache
@@ -268,15 +310,17 @@ def apply_attention_prefill_paged(params, x, cfg: ModelConfig,
 
 def apply_attention_decode_paged(params, x, cfg: ModelConfig,
                                  cache: KVCache, *, page_table, pos,
-                                 window: Optional[int] = None,
+                                 layer=0, window: Optional[int] = None,
                                  impl: Optional[str] = None):
     """One-token decode against paged KV pools.
 
     x: (B, 1, D); pos: (B,) int32 per-sequence positions (ragged batch --
     unlike the dense path there is no shared scalar position);
-    page_table: (B, n_kv) int32.  The new K/V row is scattered into page
+    page_table: (B, n_kv) int32.  The new K/V row is written into page
     ``page_table[b, pos // page_size]`` at offset ``pos % page_size``;
-    attention then reads kv_len = pos + 1 tokens through the table.
+    attention then reads kv_len = pos + 1 tokens through the table.  The
+    pools are one layer's (Hkv, P, ps, D) pair, or a layer-stacked
+    (L, Hkv, P, ps, D) pair written and read in place at ``layer``.
     Returns (out (B, 1, D), new KVCache of pools).
 
     Under an active tensor-parallel context (sharding/tp.py) the pools
@@ -287,27 +331,22 @@ def apply_attention_decode_paged(params, x, cfg: ModelConfig,
     if tpc is not None:
         return _tp_attention_decode_paged(
             params, x, cfg, cache, page_table=page_table, pos=pos,
-            window=window, tpc=tpc)
+            layer=layer, window=window, tpc=tpc)
     impl = impl or default_paged_impl()
     b = x.shape[0]
     positions = pos.astype(jnp.int32)[:, None]
     if cfg.rope_type == "mrope":   # text continuation: t=h=w=pos
         positions = jnp.broadcast_to(positions, (3, b, 1))
     q, k_new, v_new = _project_qkv(params, x, cfg, positions)
-    ps = cache.k.shape[2]
-    page = page_table[jnp.arange(b), pos // ps]
-    off = pos % ps
-    # (B, 1, Hkv, D) -> (Hkv, B, D) rows scattered at [:, page[b], off[b]]
-    k = cache.k.at[:, page, off].set(
-        k_new[:, 0].astype(cache.k.dtype).transpose(1, 0, 2))
-    v = cache.v.at[:, page, off].set(
-        v_new[:, 0].astype(cache.v.dtype).transpose(1, 0, 2))
+    cache = write_kv_chunk(cache, k_new, v_new, page_table, pos,
+                           jnp.ones_like(pos), layer)
     kv_len = pos.astype(jnp.int32) + 1
     out = fast_attention_decode(
-        q, k, v, kv_len, window=window, softcap=cfg.attn_logit_softcap,
-        impl=impl, page_table=page_table)
+        q, cache.k, cache.v, kv_len, window=window,
+        softcap=cfg.attn_logit_softcap, impl=impl, page_table=page_table,
+        layer=layer)
     out = out.reshape(b, 1, cfg.q_dim)
-    return common.dense(out, params["wo"]), KVCache(k, v)
+    return common.dense(out, params["wo"]), cache
 
 
 # ---------------------------------------------------------------------------
@@ -323,12 +362,20 @@ def apply_attention_decode_paged(params, x, cfg: ModelConfig,
 # row-parallel over per-shard query-head slices with a tiling-AllReduce
 # (core/tiled_allreduce.matmul_allreduce) over the whole mesh.
 
-def _tp_pool_spec(plan) -> P:
+def _tp_pool_spec(plan, ndim: int) -> P:
     """(Hkv, P, ps, D) pools: kv heads over the head-group axis,
-    within-page rows over the page-row axis.  The page axis stays third
-    from the end (serving/pressure.py PAGE_AXIS_FROM_END)."""
+    within-page rows over the page-row axis; a layer-stacked
+    (L, Hkv, P, ps, D) pool keeps its layer dim whole.  The page axis
+    stays third from the end (serving/pressure.py PAGE_AXIS_FROM_END)."""
     heads_ax, seq_ax = plan.axes
-    return P(heads_ax, None, seq_ax, None)
+    spec = (heads_ax, None, seq_ax, None)
+    return P(*((None,) * (ndim - 4) + spec))
+
+
+def _tp_layer_view(pool, layer, table):
+    """The dense (B, Hkv_local, n_kv * ps_local, D) view of this shard's
+    pool block at ``layer`` (stacked pools) for the LSE-merge attention."""
+    return paged_local_view(pool[layer] if pool.ndim == 5 else pool, table)
 
 
 def _tp_slice_attn_params(params, cfg: ModelConfig, gi, si, plan):
@@ -379,13 +426,13 @@ def _tp_o_proj(merged, wo_loc, si, hq_s, dtype, plan):
 
 
 def _tp_attention_decode_paged(params, x, cfg: ModelConfig,
-                               cache: KVCache, *, page_table, pos,
+                               cache: KVCache, *, page_table, pos, layer,
                                window: Optional[int], tpc):
     plan, mesh = tpc.plan, tpc.mesh
     heads_ax, seq_ax = plan.axes
-    pool_spec = _tp_pool_spec(plan)
+    pool_spec = _tp_pool_spec(plan, cache.k.ndim)
 
-    def body(prm, xb, kp, vp, table, posb):
+    def body(prm, xb, kp, vp, table, posb, lyr):
         gi = jax.lax.axis_index(heads_ax)
         si = jax.lax.axis_index(seq_ax)
         b = xb.shape[0]
@@ -394,25 +441,18 @@ def _tp_attention_decode_paged(params, x, cfg: ModelConfig,
         if cfg.rope_type == "mrope":   # text continuation: t=h=w=pos
             positions = jnp.broadcast_to(positions, (3, b, 1))
         q, k_new, v_new = _project_qkv(sp, xb, cfg, positions)
-        # masked single-row write: only the sub-shard owning the row's
-        # within-page offset writes it; everyone else redirects into its
-        # local slice of the scratch page
-        psl = kp.shape[2]
+        # only the sub-shard holding the row's within-page offset writes
+        # it
+        kp, vp = write_kv_chunk(KVCache(kp, vp), k_new, v_new, table, posb,
+                                jnp.ones_like(posb), lyr, shard=si,
+                                shards=plan.s)
+        psl = kp.shape[-2]
         ps = psl * plan.s
-        page = table[jnp.arange(b), posb // ps]
-        off = posb % ps
-        own = (off // psl) == si
-        page_t = jnp.where(own, page, 0)
-        off_t = jnp.where(own, off % psl, 0)
-        kp = kp.at[:, page_t, off_t].set(
-            k_new[:, 0].astype(kp.dtype).transpose(1, 0, 2))
-        vp = vp.at[:, page_t, off_t].set(
-            v_new[:, 0].astype(vp.dtype).transpose(1, 0, 2))
         kv_len = posb.astype(jnp.int32) + 1
         kv_pos = paged_shard_kv_positions(table.shape[1], ps, psl, si)
         out, lse = attend_with_positions(
-            q.transpose(0, 2, 1, 3), paged_local_view(kp, table),
-            paged_local_view(vp, table),
+            q.transpose(0, 2, 1, 3), _tp_layer_view(kp, lyr, table),
+            _tp_layer_view(vp, lyr, table),
             q_positions=(kv_len - 1)[:, None], kv_positions=kv_pos,
             kv_len=kv_len, causal=True, window=window,
             softcap=cfg.attn_logit_softcap)
@@ -421,20 +461,22 @@ def _tp_attention_decode_paged(params, x, cfg: ModelConfig,
 
     out, k, v = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(), P(), pool_spec, pool_spec, P(), P()),
+        in_specs=(P(), P(), pool_spec, pool_spec, P(), P(), P()),
         out_specs=(P(), pool_spec, pool_spec),
-        check_vma=False)(params, x, cache.k, cache.v, page_table, pos)
+        check_vma=False)(params, x, cache.k, cache.v, page_table, pos,
+                         jnp.asarray(layer, jnp.int32))
     return out, KVCache(k, v)
 
 
 def _tp_attention_prefill_paged(params, x, cfg: ModelConfig,
                                 cache: KVCache, *, page_table, pos_start,
-                                n_valid, window: Optional[int], tpc):
+                                n_valid, layer, window: Optional[int],
+                                tpc):
     plan, mesh = tpc.plan, tpc.mesh
     heads_ax, seq_ax = plan.axes
-    pool_spec = _tp_pool_spec(plan)
+    pool_spec = _tp_pool_spec(plan, cache.k.ndim)
 
-    def body(prm, xb, kp, vp, table, p0, nv):
+    def body(prm, xb, kp, vp, table, p0, nv, lyr):
         gi = jax.lax.axis_index(heads_ax)
         si = jax.lax.axis_index(seq_ax)
         b, s, _ = xb.shape
@@ -445,26 +487,16 @@ def _tp_attention_prefill_paged(params, x, cfg: ModelConfig,
         if cfg.rope_type == "mrope":   # text continuation: t=h=w=pos
             rope_pos = jnp.broadcast_to(positions, (3, b, s))
         q, k_new, v_new = _project_qkv(sp, xb, cfg, rope_pos)
-        # chunk scatter, owner rows only: padding rows and rows owned by
-        # other page-row sub-shards land in the local scratch slice
-        kvl, npages, psl, d = kp.shape
+        # each page-row sub-shard writes the chunk rows it holds
+        kp, vp = write_kv_chunk(KVCache(kp, vp), k_new, v_new, table, p0,
+                                nv, lyr, shard=si, shards=plan.s)
+        psl = kp.shape[-2]
         ps = psl * plan.s
-        page = table[jnp.arange(b)[:, None], positions // ps]
-        off = positions % ps
-        valid = jnp.arange(s, dtype=jnp.int32)[None] < nv[:, None]
-        own = (off // psl) == si
-        flat = jnp.where(valid & own, page * psl + off % psl, 0)
-        kp = kp.reshape(kvl, npages * psl, d).at[:, flat].set(
-            k_new.astype(kp.dtype).transpose(2, 0, 1, 3)
-        ).reshape(kvl, npages, psl, d)
-        vp = vp.reshape(kvl, npages * psl, d).at[:, flat].set(
-            v_new.astype(vp.dtype).transpose(2, 0, 1, 3)
-        ).reshape(kvl, npages, psl, d)
         kv_len = p0.astype(jnp.int32) + nv.astype(jnp.int32)
         kv_pos = paged_shard_kv_positions(table.shape[1], ps, psl, si)
         out, lse = attend_with_positions(
-            q.transpose(0, 2, 1, 3), paged_local_view(kp, table),
-            paged_local_view(vp, table),
+            q.transpose(0, 2, 1, 3), _tp_layer_view(kp, lyr, table),
+            _tp_layer_view(vp, lyr, table),
             q_positions=positions, kv_positions=kv_pos, kv_len=kv_len,
             causal=True, window=window, softcap=cfg.attn_logit_softcap)
         merged = merge_partial_attention(out, lse, seq_ax)
@@ -472,8 +504,8 @@ def _tp_attention_prefill_paged(params, x, cfg: ModelConfig,
 
     out, k, v = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(), P(), pool_spec, pool_spec, P(), P(), P()),
+        in_specs=(P(), P(), pool_spec, pool_spec, P(), P(), P(), P()),
         out_specs=(P(), pool_spec, pool_spec),
         check_vma=False)(params, x, cache.k, cache.v, page_table,
-                         pos_start, n_valid)
+                         pos_start, n_valid, jnp.asarray(layer, jnp.int32))
     return out, KVCache(k, v)

@@ -218,11 +218,11 @@ def apply_block(params, x, cfg: ModelConfig, kind: str, *, positions,
 
 def apply_block_prefill_paged(params, x, cfg: ModelConfig, kind: str,
                               cache, *, page_table, pos_start, n_valid,
-                              impl: Optional[str] = None):
+                              layer=0, impl: Optional[str] = None):
     """Chunked paged prefill: one prompt chunk (B, S, D) through the full
-    block forward, K/V scattered into the paged pools.  Rows past
-    ``n_valid`` are padding (their outputs are garbage, their K/V lands
-    in scratch)."""
+    block forward, K/V scattered into the paged pools (at ``layer`` of
+    layer-stacked pools).  Rows past ``n_valid`` are padding (their
+    outputs are garbage, their K/V lands in scratch)."""
     if kind not in ("attn", "attn_local", "moe"):
         raise NotImplementedError(
             f"paged serving supports attention-cache blocks only, "
@@ -230,17 +230,18 @@ def apply_block_prefill_paged(params, x, cfg: ModelConfig, kind: str,
     h = apply_norm(params["ln1"], x, cfg.norm_type, cfg.norm_eps)
     a, cache = attn_mod.apply_attention_prefill_paged(
         params["attn"], h, cfg, cache, page_table=page_table,
-        pos_start=pos_start, n_valid=n_valid, window=_window(cfg, kind),
-        impl=impl)
+        pos_start=pos_start, n_valid=n_valid, layer=layer,
+        window=_window(cfg, kind), impl=impl)
     x = _attn_block_tail(params, x, a, cfg, kind)
     return constrain(x, "batch", "seq", None), cache
 
 
 def apply_block_decode_paged(params, x, cfg: ModelConfig, kind: str,
-                             cache, *, page_table, pos,
+                             cache, *, page_table, pos, layer=0,
                              impl: Optional[str] = None):
     """Paged one-token decode: like apply_block_decode but positions are
-    per-sequence (B,) and the KV cache is a shared page pool."""
+    per-sequence (B,) and the KV cache is a shared page pool (read and
+    written at ``layer`` of layer-stacked pools)."""
     if kind not in ("attn", "attn_local", "moe"):
         raise NotImplementedError(
             f"paged serving supports attention-cache blocks only, "
@@ -248,7 +249,7 @@ def apply_block_decode_paged(params, x, cfg: ModelConfig, kind: str,
     h = apply_norm(params["ln1"], x, cfg.norm_type, cfg.norm_eps)
     a, cache = attn_mod.apply_attention_decode_paged(
         params["attn"], h, cfg, cache, page_table=page_table, pos=pos,
-        window=_window(cfg, kind), impl=impl)
+        layer=layer, window=_window(cfg, kind), impl=impl)
     x = _attn_block_tail(params, x, a, cfg, kind)
     return constrain(x, "batch", None, None), cache
 

@@ -239,8 +239,8 @@ class LM:
         """Shared cached-forward skeleton: thread (x, cache) through every
         segment (scanning stacked units), final-norm and project to
         logits.  ``block_fn(block_params, x, kind, block_cache) ->
-        (x, new_block_cache)`` supplies the per-block forward (one-token
-        decode or a whole prefill chunk, dense or paged caches).
+        (x, new_block_cache)`` supplies the per-block forward (the dense
+        one-token decode; paged serving runs ``_paged_segments``).
         x: (B, S, D) embedded input; returns (logits (B, S, V), cache)."""
         cfg = self.cfg
         new_cache = {}
@@ -268,6 +268,41 @@ class LM:
         logits = lm_logits(params["embedding"], x, cfg)
         return logits, new_cache
 
+    def _paged_segments(self, params, x, pools, block_fn):
+        """Paged-serving skeleton: like ``_cached_segments``, but a
+        stacked segment carries its layer-stacked pools (L, Hkv, P, ps,
+        D) through the layer scan instead of slicing them out as ``xs``
+        and restacking them as ``ys``.  ``block_fn(block_params, x, kind,
+        block_pools, layer) -> (x, new_block_pools)`` writes and reads
+        layer ``layer`` of the stacked pools in place, so the donated pool
+        buffer is never copied; a one-layer segment passes its (Hkv, P,
+        ps, D) pools with layer 0.  Returns (logits (B, S, V), pools)."""
+        cfg = self.cfg
+        new_pools = {}
+        for si, (unit, reps) in enumerate(self.segs):
+
+            def run(x, unit_params, unit_pools, layer):
+                unit_pools = dict(unit_pools)
+                for i, kind in enumerate(unit):
+                    x, unit_pools[f"u{i}"] = block_fn(
+                        unit_params[f"u{i}"], x, kind, unit_pools[f"u{i}"],
+                        layer)
+                return x, unit_pools
+
+            p, c = params[f"seg{si}"], pools[f"seg{si}"]
+            if reps == 1:
+                x, c = run(x, p, c, 0)
+            else:
+                def body(carry, xs):
+                    (x, c), (pp, layer) = carry, xs
+                    return run(x, pp, c, layer), None
+                (x, c), _ = jax.lax.scan(
+                    body, (x, c), (p, jnp.arange(reps, dtype=jnp.int32)))
+            new_pools[f"seg{si}"] = c
+        x = apply_norm(params["final_norm"], x, cfg.norm_type, cfg.norm_eps)
+        logits = lm_logits(params["embedding"], x, cfg)
+        return logits, new_pools
+
     def decode_step(self, params, token, cache, pos, *, impl=None):
         """token: (B,) int32; pos: scalar int32.  Returns (logits, cache)."""
         def block_fn(bp, x, kind, bc):
@@ -282,12 +317,12 @@ class LM:
         """Paged decode step.  token: (B,) int32; pos: (B,) int32
         per-sequence positions (ragged batch); page_table: (B, n_kv)
         int32.  Returns (logits, cache) with cache = the page pools."""
-        def block_fn(bp, x, kind, bc):
+        def block_fn(bp, x, kind, bc, layer):
             return B.apply_block_decode_paged(
                 bp, x, self.cfg, kind, bc, page_table=page_table, pos=pos,
-                impl=impl)
+                layer=layer, impl=impl)
         x = embed_tokens(params["embedding"], token[:, None], self.cfg)
-        logits, cache = self._cached_segments(params, x, cache, block_fn)
+        logits, cache = self._paged_segments(params, x, cache, block_fn)
         return logits[:, 0], cache
 
     def prefill_chunk_paged(self, params, tokens, cache, page_table,
@@ -299,15 +334,16 @@ class LM:
         (B, n_kv) int32; pos_start / n_valid: (B,) int32 runtime offsets
         -- jit traces are keyed by the chunk size C, never by prompt
         length or chunk position.  Returns (logits (B, C, V), cache);
-        logit rows past ``n_valid`` are garbage (their K/V went to the
-        scratch page).
+        logit rows past ``n_valid`` are garbage (their K/V is not
+        written).
         """
-        def block_fn(bp, x, kind, bc):
+        def block_fn(bp, x, kind, bc, layer):
             return B.apply_block_prefill_paged(
                 bp, x, self.cfg, kind, bc, page_table=page_table,
-                pos_start=pos_start, n_valid=n_valid, impl=impl)
+                pos_start=pos_start, n_valid=n_valid, layer=layer,
+                impl=impl)
         x = embed_tokens(params["embedding"], tokens, self.cfg)
-        return self._cached_segments(params, x, cache, block_fn)
+        return self._paged_segments(params, x, cache, block_fn)
 
     # ------------------------------------------------------------------
     def loss(self, params, tokens, labels, *, impl=None):

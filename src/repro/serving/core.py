@@ -1311,8 +1311,8 @@ class EngineCore:
                 return
             # slot-indexed batch like the decode step, but every row NOT
             # in the verify batch gets a scratch table row: with
-            # n_valid=0 all its K/V writes land in the scratch page, so
-            # prefilling, done and idle slots never see this launch
+            # n_valid=0 it writes no K/V, so prefilling, done and idle
+            # slots never see this launch
             buf = np.zeros((serve.max_batch, width), np.int32)
             table = np.full((serve.max_batch, mgr.max_pages_per_seq),
                             mgr.SCRATCH, np.int32)

@@ -144,3 +144,45 @@ def test_decode_block_size_invariance():
     for o in outs[1:]:
         np.testing.assert_allclose(np.asarray(o), np.asarray(outs[0]),
                                    rtol=1e-5, atol=1e-5)
+
+
+# (hq, hkv, d, window, softcap): MHA and GQA, a window, a softcap.  With
+# 128-row pages, d=64 pages are read transposed (pages_transposed), d=128
+# pages as they are.
+STACKED_CASES = [
+    (4, 4, 64, None, None),
+    (8, 2, 128, None, None),
+    (8, 2, 64, 200, None),
+    (4, 1, 128, None, 30.0),
+]
+
+
+@pytest.mark.parametrize("case", STACKED_CASES)
+def test_paged_decode_stacked_pool_reads_its_layer(case):
+    """A layer-stacked (L, Hkv, P, ps, D) pool read at layer l gives what
+    the one-layer call on pool[l] gives, for every l (kernel in interpret
+    mode, and the gather reference)."""
+    from repro.kernels.flash_decode.ref import paged_decode_reference
+    hq, hkv, d, window, softcap = case
+    n_layers, n_pages, ps, b = 3, 9, 128, 2
+    rng = np.random.default_rng(5)
+    q = jnp.asarray(rng.normal(size=(b, hq, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(n_layers, hkv, n_pages, ps, d)),
+                    jnp.float32)
+    v = jnp.asarray(rng.normal(size=(n_layers, hkv, n_pages, ps, d)),
+                    jnp.float32)
+    table = jnp.asarray(rng.permutation(np.arange(1, n_pages))[:8]
+                        .reshape(b, 4), jnp.int32)
+    kv_len = jnp.asarray([500, 77], jnp.int32)
+    kw = dict(window=window, softcap=softcap)
+    for layer in range(n_layers):
+        got = paged_flash_decode_fwd(q, k, v, table, kv_len,
+                                     jnp.int32(layer), interpret=True, **kw)
+        want = paged_flash_decode_fwd(q, k[layer], v[layer], table, kv_len,
+                                      interpret=True, **kw)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        got = paged_decode_reference(q[:, :, None], k, v, table, kv_len,
+                                     jnp.int32(layer), **kw)
+        want = paged_decode_reference(q[:, :, None], k[layer], v[layer],
+                                      table, kv_len, **kw)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

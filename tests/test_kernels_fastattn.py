@@ -214,3 +214,46 @@ def test_custom_vjp_backward_close_to_standard():
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-3, atol=1e-4)
+
+
+# (hq, hkv, d, kw): MHA and GQA, a window, a softcap.  With 128-row
+# pages, d=64 pages are read transposed (pages_transposed), d=128 pages
+# as they are.
+STACKED_CASES = [
+    (4, 4, 64, dict()),
+    (8, 2, 128, dict()),
+    (8, 2, 64, dict(window=150)),
+    (4, 1, 128, dict(softcap=30.0)),
+]
+
+
+@pytest.mark.parametrize("hq,hkv,d,kw", STACKED_CASES)
+def test_paged_prefill_stacked_pool_reads_its_layer(hq, hkv, d, kw):
+    """A layer-stacked (L, Hkv, P, ps, D) pool read at layer l gives what
+    the one-layer call on pool[l] gives, for every l (kernel in interpret
+    mode, and the gather reference)."""
+    from repro.kernels.fastattn.kernel import paged_prefill_fwd
+    from repro.kernels.flash_decode.ref import paged_prefill_reference
+    n_layers, n_pages, ps, b, c = 3, 9, 128, 2, 16
+    rng = np.random.default_rng(6)
+    q = jnp.asarray(rng.normal(size=(b, hq, c, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(n_layers, hkv, n_pages, ps, d)),
+                    jnp.float32)
+    v = jnp.asarray(rng.normal(size=(n_layers, hkv, n_pages, ps, d)),
+                    jnp.float32)
+    table = jnp.asarray(rng.permutation(np.arange(1, n_pages))[:8]
+                        .reshape(b, 4), jnp.int32)
+    pos_start = jnp.asarray([250, 3], jnp.int32)
+    kv_len = pos_start + jnp.asarray([c, 9], jnp.int32)
+    for layer in range(n_layers):
+        got = paged_prefill_fwd(q, k, v, table, pos_start, kv_len,
+                                jnp.int32(layer), block_q=8,
+                                interpret=True, **kw)
+        want = paged_prefill_fwd(q, k[layer], v[layer], table, pos_start,
+                                 kv_len, block_q=8, interpret=True, **kw)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        got = paged_prefill_reference(q, k, v, table, pos_start, kv_len,
+                                      jnp.int32(layer), **kw)
+        want = paged_prefill_reference(q, k[layer], v[layer], table,
+                                       pos_start, kv_len, **kw)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
