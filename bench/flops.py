@@ -2,7 +2,9 @@
 shapes alone.
 
 Everything here reads a configuration file of ``bench/configs`` (the
-published ``config.json`` keys) and counts what the algorithm needs:
+published ``config.json`` keys), takes its sizes from the configuration's
+model family (``bench/families/<reference>.py``: ``dims`` and the
+counts of its weights), and counts what the algorithm needs:
 matrix products as two operations per multiply-add, causal attention
 over the positions each query may see, and each KV row read once per
 call.  Work the program does beyond that (padding rows, re-fetched
@@ -14,40 +16,31 @@ from __future__ import annotations
 
 from typing import Iterable, Tuple
 
+from manifest import family_of
+
 BF16 = 2
 
 
 def dims(cfg: dict) -> dict:
-    d = cfg["hidden_size"]
-    hq = cfg["num_attention_heads"]
-    return {"L": cfg["num_hidden_layers"], "D": d, "Hq": hq,
-            "Hkv": cfg["num_key_value_heads"], "hd": d // hq,
-            "F": cfg["intermediate_size"], "V": cfg["vocab_size"],
-            "bias": bool(cfg.get("qkv_bias"))}
+    """The family's sizes; the attention counts below read ``L`` (layers),
+    ``D`` (hidden), ``Hq``, ``Hkv`` (query and KV heads), ``hd`` (head
+    size) and ``V`` (vocabulary)."""
+    return family_of(cfg).dims(cfg)
 
 
-def layer_matmul_params(cfg: dict) -> int:
-    """Weights one token multiplies in one layer: Q, K, V, O and the
-    three SwiGLU projections."""
-    m = dims(cfg)
-    qd, kvd = m["Hq"] * m["hd"], m["Hkv"] * m["hd"]
-    return m["D"] * qd + 2 * m["D"] * kvd + qd * m["D"] + 3 * m["D"] * m["F"]
+def layer_matmul_params(cfg: dict, i: int = 0) -> int:
+    """Weights one token multiplies in layer ``i`` (active experts
+    only)."""
+    return family_of(cfg).layer_matmul_params(cfg, i)
 
 
 def param_count(cfg: dict) -> int:
-    """All parameters: layers (with norms and biases), the embedding, the
-    final norm and the untied head."""
-    m = dims(cfg)
-    per = layer_matmul_params(cfg) + 2 * m["D"]
-    if m["bias"]:
-        per += (m["Hq"] + 2 * m["Hkv"]) * m["hd"]
-    head = 0 if cfg.get("tie_word_embeddings") else m["V"] * m["D"]
-    return m["L"] * per + m["V"] * m["D"] + head + m["D"]
+    """All parameters the served model holds."""
+    return family_of(cfg).param_count(cfg)
 
 
 def kv_bytes_per_token(cfg: dict) -> int:
-    m = dims(cfg)
-    return 2 * m["L"] * m["Hkv"] * m["hd"] * BF16
+    return family_of(cfg).kv_bytes_per_token(cfg)
 
 
 def attn_flops_per_layer(cfg: dict, q_len: int, ctx_start: int) -> int:
@@ -96,7 +89,7 @@ def step_flops(cfg: dict, decode_ctx: Iterable[int] = (),
     whose logits are used (one per decoding sequence, the last valid
     token of each prefill row)."""
     m = dims(cfg)
-    per_tok = 2 * m["L"] * layer_matmul_params(cfg)
+    per_tok = 2 * sum(layer_matmul_params(cfg, i) for i in range(m["L"]))
     head = 2 * m["D"] * m["V"]
     total = 0
     for n in decode_ctx:

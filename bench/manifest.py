@@ -6,8 +6,12 @@ is given in the manifest, and a traffic mix, read from
 ``bench/traffic/gen_<kind>.py``.  Each metric is read by
 ``bench/metrics/<name>.py``, or, for a variant such as
 ``device_idle_pct.chat``, by the reader of the name before its first dot.
-So a later change adds a configuration, a mix or a metric by adding files
-and entries only.
+A configuration file's ``reference`` key names its model family,
+``bench/families/<reference>.py`` (the interface is in
+``bench/families/README.md``): the program's model configuration and
+parameter layout, the float32 reference layer, and the operation counts
+of that architecture.  So a later change adds a configuration, a model
+family, a mix or a metric by adding files and entries only.
 
 ``problems()`` is the manifest check the tests run.
 """
@@ -34,11 +38,30 @@ WIDTH = re.compile(r"(hidden_size|intermediate|latent|state_size|_proj|"
                    r"experts_per_tok)")
 
 
+# what a family module has to define (``head`` and ``KERNELS`` are
+# optional: ``reference.default_head`` and ``()``)
+FAMILY_NAMES = ("program", "params", "layer_shapes", "MATRICES", "block",
+                "dims", "layer_matmul_params", "param_count",
+                "kv_bytes_per_token")
+_FAMILIES: Dict[str, object] = {}    # file -> module: each runs once
+
+
 def _load_module(path: str, name: str):
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def family_of(cfg: dict):
+    """The model family of a configuration: the module in the file its
+    ``family_file`` names (``Manifest.config`` sets it to the checkout's
+    ``bench/families/<reference>.py``)."""
+    path = cfg["family_file"]
+    if path not in _FAMILIES:
+        _FAMILIES[path] = _load_module(
+            path, "family_" + os.path.basename(path)[:-3])
+    return _FAMILIES[path]
 
 
 class Manifest:
@@ -62,9 +85,16 @@ class Manifest:
         raise KeyError(f"no config {name!r} in BENCHMARK.json")
 
     def config(self, cell: dict) -> dict:
+        """The configuration file, with ``family_file``: this checkout's
+        ``bench/families/<reference>.py``."""
         with open(os.path.join(self.root,
                                self.config_entry(cell["config"])["file"])) as f:
-            return json.load(f)
+            cfg = json.load(f)
+        cfg["family_file"] = self.family_path(cfg.get("reference"))
+        return cfg
+
+    def family_path(self, reference) -> str:
+        return os.path.join(self.bench, "families", f"{reference}.py")
 
     def traffic_path(self, name: str) -> str:
         return os.path.join(self.bench, "traffic", f"{name}.json")
@@ -147,6 +177,8 @@ class Manifest:
             path = os.path.join(self.root, c["file"])
             if not os.path.exists(path):
                 out.append(f"config {c['name']}: missing {c['file']}")
+            else:
+                out += self._family_problems(c["name"], path)
             for k in c["reduced"]:
                 if not NAME.match(k) or WIDTH.search(k):
                     out.append(f"config {c['name']}: reduced {k!r}")
@@ -197,3 +229,15 @@ class Manifest:
         if len(json.dumps(d)) > 64 * 1024:
             out.append("BENCHMARK.json over 64 KiB")
         return out
+
+    def _family_problems(self, config: str, path: str) -> List[str]:
+        with open(path) as f:
+            ref = json.load(f).get("reference")
+        if not (isinstance(ref, str) and NAME.match(ref)
+                and os.path.exists(self.family_path(ref))):
+            return [f"config {config}: reference {ref!r} names no file "
+                    "under bench/families/"]
+        family = family_of({"family_file": self.family_path(ref)})
+        missing = [n for n in FAMILY_NAMES if not hasattr(family, n)]
+        return [f"family {ref}: lacks {', '.join(missing)}"] if missing \
+            else []

@@ -1,22 +1,24 @@
-"""The plain reference: a dense GQA transformer (Phi-3, Qwen2) in float32.
+"""The plain reference: the configuration's model family in float32.
 
 It follows the published architecture, not the program under test, and
-imports nothing of it: token embedding, then per layer RMSNorm, Q/K/V
-projections (with biases where the configuration has them), rotary
-position embedding on the two halves of each head, causal softmax
-attention with each KV head shared by ``Hq / Hkv`` query heads, the
-output projection and a residual, then RMSNorm, a SwiGLU MLP and a
-residual; a final RMSNorm and the untied head.  Weights come from
-``weights.py`` for the same seed, rounded to bfloat16 as they are served,
-and every product runs in float32 at the highest precision.  Layers are
-made and applied one at a time and sequences one at a time, so the
-reference fits beside nothing else on one chip.
+imports nothing of it.  The layers are the family's (``block`` of
+``bench/families/<reference>.py``, given each layer's index); before
+them comes the token embedding, after them the family's ``head`` (by
+default a final RMSNorm and the head matrix, the embedding where the
+configuration ties them).  Weights come from ``weights.py``
+for the same seed, rounded to bfloat16 as they are served, and every
+product runs in float32 at the highest precision.  Layers are made and
+applied one at a time and sequences one at a time, so the reference fits
+beside nothing else on one chip.  ``rms``, ``rope`` and ``attention``
+are the float32 pieces a family's layer is built from.
 
-``control="int8"`` also computes the same forward with every matrix of
-the layers and the head quantised to int8 per output column (symmetric,
-one scale per column), the next precision below bfloat16 that a program
-could be tempted to serve in; ``control="e4m3"`` does the same in
-float8 e4m3 (each column scaled to the format's largest value, 448).
+``control="int8"`` also computes the same forward with every matrix the
+family lists in ``MATRICES`` and the head quantised to int8 per output
+column (symmetric, one scale per column), the next precision below
+bfloat16 that a program could be tempted to serve in; ``control="e4m3"``
+does the same in float8 e4m3 (each column scaled to the format's largest
+value, 448).  A matrix with a leading axis (one per expert) is
+quantised per output column of each.
 """
 from __future__ import annotations
 
@@ -27,15 +29,15 @@ import jax.numpy as jnp
 import numpy as np
 
 import weights as W
+from manifest import family_of
 
-MATRICES = ("q", "k", "v", "o", "gate", "up", "down")
 HEAD_ROWS = 128
 
 
 def int8_roundtrip(w: jax.Array) -> jax.Array:
-    """Quantise a (in, out) float32 matrix to int8 with one scale per
+    """Quantise a (..., in, out) float32 matrix to int8 with one scale per
     output column and back."""
-    scale = jnp.max(jnp.abs(w), axis=0, keepdims=True) / 127.0
+    scale = jnp.max(jnp.abs(w), axis=-2, keepdims=True) / 127.0
     scale = jnp.where(scale == 0, 1.0, scale)
     return jnp.clip(jnp.round(w / scale), -127, 127) * scale
 
@@ -54,9 +56,9 @@ def e4m3_round(x: jax.Array) -> jax.Array:
 
 
 def e4m3_roundtrip(w: jax.Array) -> jax.Array:
-    """Quantise a (in, out) float32 matrix to float8 e4m3 with one scale
-    per output column (its largest magnitude to 448) and back."""
-    scale = jnp.max(jnp.abs(w), axis=0, keepdims=True) / 448.0
+    """Quantise a (..., in, out) float32 matrix to float8 e4m3 with one
+    scale per output column (its largest magnitude to 448) and back."""
+    scale = jnp.max(jnp.abs(w), axis=-2, keepdims=True) / 448.0
     scale = jnp.where(scale == 0, 1.0, scale)
     return e4m3_round(w / scale) * scale
 
@@ -64,11 +66,11 @@ def e4m3_roundtrip(w: jax.Array) -> jax.Array:
 QUANT = {"int8": int8_roundtrip, "e4m3": e4m3_roundtrip}
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x: (S, H, hd); rotate the first and second halves of each head."""
     hd = x.shape[-1]
     freqs = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
@@ -78,23 +80,20 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _block(cfg: dict, w: Dict[str, jax.Array], x, n_valid):
-    """One layer over one padded sequence x: (S, D); rows past
-    ``n_valid`` are padding (causality keeps them out of real rows)."""
-    s, d = x.shape
-    hq, hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd = d // hq
-    eps = cfg["rms_norm_eps"]
+def default_head(cfg, x, norm, w):
+    """A family's ``head`` where it defines none: the final RMSNorm, then
+    the (D, V) head matrix."""
+    return rms(x, norm, cfg["rms_norm_eps"]) @ w
+
+
+def attention(q, k, v):
+    """Causal softmax attention of q: (S, Hq, hd) over k, v: (S, Hkv, hd),
+    each KV head shared by ``Hq / Hkv`` query heads; returns the heads'
+    outputs side by side, (S, Hq * hd)."""
+    s, hq, hd = q.shape
+    hkv = k.shape[1]
     pos = jnp.arange(s)
-    h = _rms(x, w["input_norm"], eps)
-    q, k, v = h @ w["q"], h @ w["k"], h @ w["v"]
-    if "q_bias" in w:
-        q, k, v = q + w["q_bias"], k + w["k_bias"], v + w["v_bias"]
-    q = _rope(q.reshape(s, hq, hd), pos, cfg["rope_theta"])
-    k = _rope(k.reshape(s, hkv, hd), pos, cfg["rope_theta"])
-    v = v.reshape(s, hkv, hd)
-    rep = hq // hkv
-    q = q.reshape(s, hkv, rep, hd)
+    q = q.reshape(s, hkv, hq // hkv, hd)
     out = []
     blk = 512 if s % 512 == 0 else s
     for q0 in range(0, s, blk):       # query blocks bound the score tensor
@@ -104,16 +103,13 @@ def _block(cfg: dict, w: Dict[str, jax.Array], x, n_valid):
         sc = jnp.where(causal, sc, -jnp.inf)
         p = jax.nn.softmax(sc, axis=-1)
         out.append(jnp.einsum("grqk,kgd->qgrd", p, v))
-    a = jnp.concatenate(out, 0).reshape(s, hq * hd)
-    x = x + a @ w["o"]
-    h = _rms(x, w["post_norm"], eps)
-    return x + (jax.nn.silu(h @ w["gate"]) * (h @ w["up"])) @ w["down"]
+    return jnp.concatenate(out, 0).reshape(s, hq * hd)
 
 
 class Reference:
     """Float32 logits of the configuration for the weights of ``seed``;
-    ``control`` (a key of ``QUANT``) adds the forward with its matrices
-    in that precision beside it."""
+    ``control`` (a key of ``QUANT``) adds the forward with the family's
+    ``MATRICES`` in that precision beside it."""
 
     def __init__(self, cfg: dict, seed: int, max_len: int,
                  control: Optional[str] = None):
@@ -121,23 +117,27 @@ class Reference:
             raise ValueError(f"unknown control {control!r}")
         self.cfg, self.max_len, self.control = cfg, max_len, control
         self.key = W.base_key(seed)
+        self.family = fam = family_of(cfg)
         cfg_ = cfg
         quantise = QUANT.get(control)
 
-        def layer_f32(key, i, quant):
+        def layer_f32(key, i, shapes, quant):
             w = {n: a.astype(jnp.float32)
-                 for n, a in W.layer(cfg_, key, i).items()}
+                 for n, a in W.layer(cfg_, key, i, dict(shapes)).items()}
             if quant:
-                w.update({n: quantise(w[n]) for n in MATRICES})
+                w.update({n: quantise(w[n]) for n in fam.MATRICES
+                          if n in w})
             return w
 
-        self._layer = jax.jit(layer_f32, static_argnums=(2,))
+        # one program for each set of tensor shapes (each kind of layer)
+        self._layer = jax.jit(layer_f32, static_argnums=(2, 3))
 
-        def block(w, x, n):
+        def block(w, x, n, i):
             with jax.default_matmul_precision("highest"):
-                return _block(cfg_, w, x, n)
+                return fam.block(cfg_, w, x, n, i)
 
         self._block = jax.jit(block)
+        family_head = getattr(fam, "head", default_head)
 
         def head(hw, fn, x, start):
             # a fixed block of rows from ``start`` (one program for every
@@ -145,19 +145,24 @@ class Reference:
             rows = jnp.take(x, start + jnp.arange(HEAD_ROWS), axis=0,
                             mode="clip")
             with jax.default_matmul_precision("highest"):
-                return _rms(rows, fn, cfg_["rms_norm_eps"]) @ hw
+                return family_head(cfg_, rows, fn, hw)
 
         self._head = jax.jit(head)
         self._quant = jax.jit(quantise) if quantise else None
+
+    def _layer_of(self, i: int, quant: bool) -> Dict[str, jax.Array]:
+        shapes = self.family.layer_shapes(self.cfg, i)
+        return self._layer(self.key, jnp.int32(i),
+                           tuple((n, tuple(s)) for n, s in shapes.items()),
+                           quant)
 
     def roundtrip_stats(self) -> Dict[str, float]:
         """What the control's quantisation does to the served weights, on
         the device through the control's own programs: the share of the
         elements of layer 0's matrices and of the head that it changes,
         and its error (Frobenius norm of the change over the matrix's)."""
-        ws = self._layer(self.key, jnp.int32(0), False)
-        qs = self._layer(self.key, jnp.int32(0), True)
-        pairs = [(ws[n], qs[n]) for n in MATRICES]
+        ws, qs = self._layer_of(0, False), self._layer_of(0, True)
+        pairs = [(ws[n], qs[n]) for n in self.family.MATRICES if n in ws]
         hw = W.lm_head(self.cfg, self.key).astype(jnp.float32)
         pairs.append((hw, self._quant(hw)))
         changed = sum(float(jnp.sum(w != q)) for w, q in pairs)
@@ -186,9 +191,9 @@ class Reference:
         del emb
         for i in range(L):
             for name, xs in streams.items():
-                w = self._layer(self.key, jnp.int32(i), name != "ref")
+                w = self._layer_of(i, name != "ref")
                 for j, (toks, _, _) in enumerate(seqs):
-                    xs[j] = self._block(w, xs[j], len(toks))
+                    xs[j] = self._block(w, xs[j], len(toks), jnp.int32(i))
                 del w
         hw = W.lm_head(self.cfg, self.key).astype(jnp.float32)
         fn = W.final_norm(self.cfg).astype(jnp.float32)

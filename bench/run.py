@@ -25,7 +25,9 @@ then:
    request due in the window has its first token;
 5. reads the peak device memory, frees the engine, and checks a sample
    of the finished greedy requests against the float32 reference
-   (``reference.py``) within the cell's limits (``bench/checks/<cell>.json``);
+   (``reference.py`` around the layer of the configuration's model
+   family, ``bench/families/<reference>.py``) within the cell's limits
+   (``bench/checks/<cell>.json``);
 6. prints the generator's lateness and the compilations inside the
    window, the compared numbers beside their limits (as the last lines
    of standard error), and as the last line of standard output one JSON
@@ -58,7 +60,7 @@ ROOT = os.path.dirname(BENCH)
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
-from manifest import Manifest  # noqa: E402
+from manifest import Manifest, family_of  # noqa: E402
 
 TRACE_S = 10.0          # longest traced stretch of a window
 DRAIN_S = 60.0          # longest wait past the close for first tokens
@@ -138,6 +140,7 @@ class Run:
     trace_t1: float = math.nan
     decode_kernel: str = ""
     prefill_kernel: str = ""
+    family: object = None                   # bench/families/<reference>.py
 
 
 def sampling_params(spec):
@@ -472,6 +475,7 @@ def main(argv=None, *, root: str = ROOT, require_chip: bool = True,
     man = Manifest(root)
     cell = man.cell(args.workload)
     cfg = man.config(cell)
+    family = family_of(cfg)
     mix = man.traffic(cell)
     with open(os.path.join(man.bench, "checks", f"{cell['name']}.json")) as f:
         limits = json.load(f)
@@ -535,14 +539,15 @@ def main(argv=None, *, root: str = ROOT, require_chip: bool = True,
         path = trace_reduce.find_xplane(trace_dir)
         if path is not None:
             reduced = trace_reduce.reduce_file(
-                path, [system.DECODE_KERNEL, system.PREFILL_KERNEL])
+                path, (system.DECODE_KERNEL, system.PREFILL_KERNEL)
+                + tuple(getattr(family, "KERNELS", ())))
         shutil.rmtree(trace_dir, ignore_errors=True)
     run = Run(cfg=cfg, mix=mix, cell=cell, peaks=peaks, chips=cell["chips"],
               t0=out["t0"], t1=out["t1"], recs=recs, setup_s=setup_s,
               counters=counters, usable_pages=usable, launches=launches,
               trace=reduced, trace_t0=out["tr_t0"], trace_t1=out["tr_t1"],
               decode_kernel=system.DECODE_KERNEL,
-              prefill_kernel=system.PREFILL_KERNEL)
+              prefill_kernel=system.PREFILL_KERNEL, family=family)
     lat = sorted(out["lateness"])
     print(f"window {out['t1'] - out['t0']:.3f} s, {out['steps']} steps; "
           f"drain {drain_s:.3f} s; requests due {len(recs)}, "

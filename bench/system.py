@@ -3,11 +3,12 @@ cell.
 
 This is the one module of the benchmark that imports the program (from
 ``src/`` of the checkout).  It makes the program's model configuration
-from the configuration file, lays the benchmark's own random weights
-(``weights.py``) into the program's parameter tree in one jitted call,
-sets the engine up from the mix's ``engine`` block (its page pools built
-under jit, ``fill_pools``), and warms every program the cell's traffic
-can launch through the engine's public ``add_request``/``step``.
+and lays the benchmark's own random weights (``weights.py``) into the
+program's parameter tree in one jitted call, both as the configuration's
+model family says (``bench/families/<reference>.py``).  It sets the
+engine up from the mix's ``engine`` block (its page pools built under
+jit, ``fill_pools``), and warms every program the cell's traffic can
+launch through the engine's public ``add_request``/``step``.
 """
 from __future__ import annotations
 
@@ -16,10 +17,10 @@ import sys
 from typing import Iterable, List
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 import weights as W
+from manifest import family_of
 
 SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))), "src")
@@ -39,56 +40,30 @@ PREFILL_KERNEL = "paged_prefill_fwd"
 
 
 def model_config(cfg: dict) -> ModelConfig:
-    d, hq = cfg["hidden_size"], cfg["num_attention_heads"]
-    return ModelConfig(
-        name=cfg.get("model_type", "dense"), family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=d, num_heads=hq,
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=d // hq,
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        block_pattern=("attn",), qkv_bias=bool(cfg.get("qkv_bias")),
-        rope_theta=float(cfg["rope_theta"]), norm_type="rmsnorm",
-        norm_eps=float(cfg["rms_norm_eps"]), mlp_type="swiglu",
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        dtype="bfloat16", param_dtype="bfloat16")
-
-
-# program parameter leaf (below the stacked layer segment) -> weights.py
-LAYER_LEAVES = {("attn", "wq"): "q", ("attn", "wk"): "k", ("attn", "wv"): "v",
-                ("attn", "wo"): "o", ("attn", "bq"): "q_bias",
-                ("attn", "bk"): "k_bias", ("attn", "bv"): "v_bias",
-                ("mlp", "w_gate"): "gate", ("mlp", "w_up"): "up",
-                ("mlp", "w_down"): "down", ("ln1", "scale"): "input_norm",
-                ("ln2", "scale"): "post_norm"}
+    return ModelConfig(**family_of(cfg).program(cfg))
 
 
 def make_params(model, cfg: dict, seed: int):
     """The served weights for ``seed`` in the program's tree, made on the
-    device by one jitted call.  Fails if the program's tree has a leaf
-    this mapping does not know, or a shape other than ``weights.py``'s."""
+    device by one jitted call (the family's ``params`` lays them out).
+    Fails if the result does not have the program's tree, shapes and
+    types."""
     want = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    if set(want) != {"embedding", "final_norm", "seg0"} \
-            or set(want["seg0"]) != {"u0"}:
-        raise ValueError(f"unexpected parameter tree {sorted(want)}")
-    L = cfg["num_hidden_layers"]
+    fam = family_of(cfg)
 
     def make(key):
-        layers = jax.vmap(lambda i: W.layer(cfg, key, i))(jnp.arange(L))
-        unit = {}
-        for (grp, leaf), name in LAYER_LEAVES.items():
-            if leaf in want["seg0"]["u0"].get(grp, {}):
-                unit.setdefault(grp, {})[leaf] = layers[name]
-        emb = {"embed": W.embed(cfg, key)}
-        if "lm_head" in want["embedding"]:
-            emb["lm_head"] = W.lm_head(cfg, key)
-        return {"embedding": emb, "final_norm": {"scale": W.final_norm(cfg)},
-                "seg0": {"u0": unit}}
+        return fam.params(want, cfg,
+                          lambda start, stop: W.layers(cfg, key, start, stop),
+                          W.embed(cfg, key), W.lm_head(cfg, key),
+                          W.final_norm(cfg))
 
     key = W.base_key(seed)     # an argument: one program for every seed
     got = jax.eval_shape(make, key)
     if jax.tree.structure(got) != jax.tree.structure(want) or any(
             a.shape != b.shape or a.dtype != b.dtype
             for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want))):
-        raise ValueError("weights.py does not fit the program's tree")
+        raise ValueError("the family's weights do not fit the program's "
+                         "tree")
     return jax.jit(make)(key)
 
 

@@ -18,11 +18,21 @@ if BENCH not in sys.path:
 BACKLOG = "tiny.backlog"
 CHAT = "tiny.chat"
 
-TINY = {"hidden_size": 64, "num_attention_heads": 4,
-        "num_key_value_heads": 2, "num_hidden_layers": 2,
-        "intermediate_size": 128, "vocab_size": 512, "qkv_bias": True,
-        "rope_theta": 10000.0, "rms_norm_eps": 1e-5,
-        "initializer_range": 0.02, "tie_word_embeddings": False}
+
+
+def with_family(cfg: dict) -> dict:
+    """A configuration as ``Manifest.config`` gives it: with the
+    ``family_file`` its ``reference`` names, here in this ``bench/``."""
+    return dict(cfg, family_file=os.path.join(
+        BENCH, "families", f"{cfg['reference']}.py"))
+
+
+TINY = with_family({
+    "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
+    "num_hidden_layers": 2, "intermediate_size": 128, "vocab_size": 512,
+    "qkv_bias": True, "rope_theta": 10000.0, "rms_norm_eps": 1e-5,
+    "initializer_range": 0.02, "tie_word_embeddings": False,
+    "reference": "dense_gqa"})
 ENGINE = {"max_batch": 4, "max_seq_len": 256, "page_size": 16,
           "num_pages": 40, "prefill_chunk": 32, "prefix_cache": True,
           "preempt_policy": "recompute"}
@@ -36,7 +46,7 @@ def make_checkout(root: str) -> str:
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     b = os.path.join(root, "bench")
     with open(f"{b}/configs/tiny.json", "w") as f:
-        json.dump(TINY, f)
+        json.dump({k: v for k, v in TINY.items() if k != "family_file"}, f)
     with open(f"{b}/traffic/tiny-backlog.json", "w") as f:
         json.dump({"kind": "backlog", "requests": 40, "block": 8,
                    "prompt": {"median": 40, "sigma": 0.5, "min": 16,

@@ -85,9 +85,7 @@ def _run_for(metric_names, trace):
     from conftest import ROOT
     from manifest import Manifest
     man = Manifest(ROOT)
-    with open(os.path.join(ROOT, "bench", "configs",
-                           "phi3-mini-3.8b.json")) as f:
-        cfg = json.load(f)
+    cfg = man.config({"config": "phi3-mini-3.8b"})
     launches = [{"kind": "decode", "t": 0.5 + i,
                  "ctx": [700 + 37 * j + i for j in range(8)]}
                 for i in range(3)]

@@ -46,6 +46,25 @@ def _missing(kind):
     return edit
 
 
+def _reference(name):
+    def edit(d, root):
+        path = os.path.join(root, "bench", "configs", "tiny.json")
+        with open(path) as f:
+            cfg = json.load(f)
+        with open(path, "w") as f:
+            json.dump(dict(cfg, reference=name), f)
+    return edit
+
+
+def _family_without_block(d, root):
+    fams = os.path.join(root, "bench", "families")
+    with open(os.path.join(fams, "dense_gqa.py")) as f:
+        src = f.read()
+    with open(os.path.join(fams, "no_block.py"), "w") as f:
+        f.write(src.replace("def block(", "def not_block("))
+    _reference("no_block")(d, root)
+
+
 @pytest.mark.parametrize("edit,needle", [
     (lambda d, r: d["workloads"][0].update(name="bad name"), "bad name"),
     (lambda d, r: d["per_layer"][0].update(name="a,b"), "bad name"),
@@ -61,9 +80,13 @@ def _missing(kind):
      "reduced"),
     (lambda d, r: d["end_to_end"][0].update(bound=0.3), "bound"),
     (lambda d, r: d["workloads"][0].update(extra=1), "keys"),
+    (_reference("moe_unknown"), "names no file under bench/families/"),
+    (_reference("../configs/tiny"), "names no file under bench/families/"),
+    (_family_without_block, "family no_block: lacks block"),
 ], ids=["cell-name", "metric-name", "unit-space", "unit-greek",
         "moves-not-reported", "four-chips", "config-file", "traffic-file",
-        "metric-file", "reduced-width", "bound", "extra-key"])
+        "metric-file", "reduced-width", "bound", "extra-key",
+        "family-unknown", "family-path", "family-incomplete"])
 def test_broken_manifest_is_refused(tmp_path, edit, needle):
     problems = _broken(tmp_path, edit)
     assert any(needle in p for p in problems), problems

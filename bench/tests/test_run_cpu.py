@@ -1,8 +1,8 @@
 """Whole runs of ``bench/run.py`` on the CPU at a tiny size, skipping
 only the look for a chip: a sound run is correct, a run whose timed path
 is broken underneath is not, a later change can add a configuration, a
-mix and a metric as files alone, and without a chip the command prints
-no result."""
+mix, a metric and a model family as files alone, and without a chip the
+command prints no result."""
 import json
 import os
 import subprocess
@@ -159,6 +159,81 @@ def test_new_config_mix_and_metric_are_files_only(checkout):
     rc, res = run_cell(checkout, "tiny-mha.short", "--trace", "1")
     assert rc == 0 and res["correct"], res
     assert res["metrics"]["requests_finished"]["value"] > 0
+
+
+def _add_family_cell(checkout, family, source):
+    """A family file, a tiny configuration naming it, and a backlog cell
+    of that configuration with its check, added as files and entries."""
+    b = os.path.join(checkout, "bench")
+    with open(f"{b}/families/{family}.py", "w") as f:
+        f.write(source)
+    with open(f"{b}/configs/tiny.json") as f:
+        cfg = json.load(f)
+    with open(f"{b}/configs/tiny-{family}.json", "w") as f:
+        json.dump(dict(cfg, reference=family), f)
+    cell = f"tiny-{family}.backlog"
+    with open(f"{b}/checks/{BACKLOG}.json") as f:
+        check = json.load(f)
+    with open(f"{b}/checks/{cell}.json", "w") as f:
+        json.dump(check, f)
+    path = os.path.join(checkout, "BENCHMARK.json")
+    with open(path) as f:
+        man = json.load(f)
+    man["configs"].append({"name": f"tiny-{family}", "source": "test",
+                           "file": f"bench/configs/tiny-{family}.json",
+                           "reduced": [], "why": "test"})
+    man["workloads"].append({"name": cell, "config": f"tiny-{family}",
+                             "traffic": "tiny-backlog", "chips": 1,
+                             "why": "test"})
+    for m in man["end_to_end"] + man["per_layer"]:
+        if BACKLOG in m.get("workloads", ()):
+            m["workloads"].append(cell)
+    with open(path, "w") as f:
+        json.dump(man, f)
+    return cell
+
+
+def test_new_family_is_files_only(checkout, monkeypatch):
+    """A family of its own (a copy of the dense one that names one more
+    kernel) joins as files and entries: the manifest check passes, a
+    traced run is correct and hands the family's kernel to the trace's
+    reduction; a family whose layer drops the MLP's residual makes the
+    same run not correct, so the reference is the family's; a
+    configuration naming no family file is reported."""
+    import trace_reduce
+    from manifest import Manifest
+    with open(os.path.join(checkout, "bench", "families",
+                           "dense_gqa.py")) as f:
+        dense = f.read()
+    cell = _add_family_cell(checkout, "dense_gqa_k",
+                            dense + '\nKERNELS = ("tiny_extra_kernel",)\n')
+    assert Manifest(checkout).problems() == []
+    asked = []
+    reduce_file = trace_reduce.reduce_file
+
+    def spy(path, kernels=()):
+        asked.append(tuple(kernels))
+        return reduce_file(path, kernels)
+
+    monkeypatch.setattr(trace_reduce, "reduce_file", spy)
+    rc, res = run_cell(checkout, cell, "--trace", "1")
+    assert rc == 0 and res["correct"], res
+    assert asked and asked[-1][-1] == "tiny_extra_kernel", asked
+
+    residual = "    return x + (jax.nn.silu(h @ w[\"gate\"])"
+    assert residual in dense
+    broken = _add_family_cell(checkout, "dense_gqa_no_mlp", dense.replace(
+        residual, "    return x + 0 * (jax.nn.silu(h @ w[\"gate\"])"))
+    rc, res = run_cell(checkout, broken)
+    assert rc == 0 and res["correct"] is False, res["check"]
+
+    b = os.path.join(checkout, "bench")
+    with open(f"{b}/configs/tiny-dense_gqa_k.json") as f:
+        cfg = json.load(f)
+    with open(f"{b}/configs/tiny-dense_gqa_k.json", "w") as f:
+        json.dump(dict(cfg, reference="dense_gqa_missing"), f)
+    assert any("reference 'dense_gqa_missing' names no file" in p
+               for p in Manifest(checkout).problems())
 
 
 def test_no_chip_no_result(tmp_path):

@@ -5,12 +5,15 @@ import math
 import pytest
 
 import flops
+from conftest import with_family
 from stats import percentile, ttft_ms
 
 # 2 layers, d 8, 2 query heads of 4 sharing 1 KV head, d_ff 16, vocab 10
-TOY = {"num_hidden_layers": 2, "hidden_size": 8, "num_attention_heads": 2,
-       "num_key_value_heads": 1, "intermediate_size": 16, "vocab_size": 10,
-       "qkv_bias": True, "tie_word_embeddings": False}
+TOY = with_family({
+    "num_hidden_layers": 2, "hidden_size": 8, "num_attention_heads": 2,
+    "num_key_value_heads": 1, "intermediate_size": 16, "vocab_size": 10,
+    "qkv_bias": True, "tie_word_embeddings": False,
+    "reference": "dense_gqa"})
 
 
 def test_percentile_nearest_rank_over_all_samples():
@@ -84,9 +87,9 @@ def test_published_sizes():
     here = os.path.join(os.path.dirname(os.path.dirname(__file__)),
                         "configs")
     with open(os.path.join(here, "phi3-mini-3.8b.json")) as f:
-        phi3 = json.load(f)
+        phi3 = with_family(json.load(f))
     with open(os.path.join(here, "qwen2.5-32b-l8.json")) as f:
-        qwen = json.load(f)
+        qwen = with_family(json.load(f))
     assert flops.param_count(phi3) * 2 == 7_642_159_104
     assert flops.kv_bytes_per_token(phi3) == 393_216
     assert flops.kv_bytes_per_token(qwen) == 32_768
