@@ -8,7 +8,9 @@ program's parameter tree in one jitted call, both as the configuration's
 model family says (``bench/families/<reference>.py``).  It sets the
 engine up from the mix's ``engine`` block (its page pools built under
 jit, ``fill_pools``), and warms every program the cell's traffic can
-launch through the engine's public ``add_request``/``step``.
+launch through the engine's public ``add_request``/``step``.  Where the
+mix asks for ``tp`` > 1, the weights are made replicated on the engine's
+mesh and the pools sharded over it, each on its devices from the start.
 """
 from __future__ import annotations
 
@@ -18,6 +20,7 @@ from typing import Iterable, List
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 import weights as W
 from manifest import family_of
@@ -28,10 +31,12 @@ if SRC not in sys.path:
     sys.path.insert(0, SRC)
 
 from repro.config import ModelConfig, ParallelConfig, ServeConfig  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.serving.core import EngineCore  # noqa: E402
 from repro.serving.pressure import copy_pages  # noqa: E402
 from repro.serving.scheduler import SamplingParams  # noqa: E402
+from repro.sharding.tp import plan_tp  # noqa: E402
 
 # the program's decode and prefill attention kernels, as the trace names
 # their custom calls
@@ -43,11 +48,11 @@ def model_config(cfg: dict) -> ModelConfig:
     return ModelConfig(**family_of(cfg).program(cfg))
 
 
-def make_params(model, cfg: dict, seed: int):
+def make_params(model, cfg: dict, seed: int, sharding=None):
     """The served weights for ``seed`` in the program's tree, made on the
-    device by one jitted call (the family's ``params`` lays them out).
-    Fails if the result does not have the program's tree, shapes and
-    types."""
+    device by one jitted call (the family's ``params`` lays them out),
+    every leaf with ``sharding`` where one is given.  Fails if the result
+    does not have the program's tree, shapes and types."""
     want = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     fam = family_of(cfg)
 
@@ -64,7 +69,9 @@ def make_params(model, cfg: dict, seed: int):
             for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want))):
         raise ValueError("the family's weights do not fit the program's "
                          "tree")
-    return jax.jit(make)(key)
+    if sharding is None:
+        return jax.jit(make)(key)
+    return jax.jit(make, out_shardings=sharding)(key)
 
 
 def serve_config(engine: dict) -> ServeConfig:
@@ -74,24 +81,54 @@ def serve_config(engine: dict) -> ServeConfig:
         prefill_chunk=engine["prefill_chunk"],
         prefix_cache=engine.get("prefix_cache", True),
         preempt_policy=engine.get("preempt_policy", "auto"),
-        paged_impl=engine.get("paged_impl", "auto"))
+        paged_impl=engine.get("paged_impl", "auto"),
+        tp=engine.get("tp", 1))
+
+
+def tp_mesh(mcfg: ModelConfig, serve: ServeConfig):
+    """The tensor-parallel engine's mesh, built as ``EngineCore`` builds
+    its own (``plan_tp``, then ``make_mesh``); None where ``tp`` is 1."""
+    if serve.tp <= 1:
+        return None
+    plan = plan_tp(mcfg, serve.tp, serve.page_size,
+                   collectives=serve.tp_collectives,
+                   ar_chunks=serve.tp_ar_chunks,
+                   first_chunk_frac=serve.tp_first_chunk_frac)
+    return make_mesh(plan.mesh_shape, plan.axes)
 
 
 def fill_pools(core: EngineCore) -> None:
-    """Materialise the page pools under jit.  The engine's own first step
-    builds them eagerly, layer by layer and then stacked, which holds
-    more than two pools at once: beside phi3-mini's weights an 80-page
-    pool (4.03 GB) does not fit that way on a 16 GB chip."""
+    """Materialise the page pools under jit, on a tensor-parallel
+    engine's mesh as ``LM.paged_cache_sharding`` shards them, so each
+    device makes only its shard.  The engine's own first step builds
+    them eagerly, layer by layer and then stacked, which holds more than
+    two pools at once: beside phi3-mini's weights an 80-page pool (4.03
+    GB) does not fit that way on a 16 GB chip."""
     mgr = core.mgr
-    core.pools = jax.jit(lambda: core.model.init_paged_cache(
-        mgr.num_pages, mgr.page_size))()
+
+    def make():
+        return core.model.init_paged_cache(mgr.num_pages, mgr.page_size)
+
+    if core.tp_mesh is None:
+        core.pools = jax.jit(make)()
+    else:
+        core.pools = jax.jit(make, out_shardings=core.model
+                             .paged_cache_sharding(core.tp_mesh,
+                                                   mgr.num_pages,
+                                                   mgr.page_size))()
 
 
 def build(cfg: dict, engine: dict, seed: int):
+    """The engine for one cell.  On a tensor-parallel engine the weights
+    are made replicated on its mesh, so ``EngineCore``'s own placement
+    of them moves nothing."""
     mcfg = model_config(cfg)
     model = build_model(mcfg, ParallelConfig())
-    params = make_params(model, cfg, seed)
-    core = EngineCore(model, params, mcfg, serve_config(engine))
+    serve = serve_config(engine)
+    mesh = tp_mesh(mcfg, serve)
+    params = make_params(model, cfg, seed, None if mesh is None
+                         else NamedSharding(mesh, PartitionSpec()))
+    core = EngineCore(model, params, mcfg, serve)
     fill_pools(core)
     return core
 

@@ -1,6 +1,6 @@
 """Shared fixtures of the benchmark's CPU tests: ``bench/`` on the path,
 and a throwaway checkout holding a copy of ``bench/`` plus a tiny
-configuration and two tiny mixes, so a whole run fits on the CPU."""
+configuration and three tiny mixes, so a whole run fits on the CPU."""
 from __future__ import annotations
 
 import json
@@ -17,7 +17,10 @@ if BENCH not in sys.path:
 
 BACKLOG = "tiny.backlog"
 CHAT = "tiny.chat"
-
+DOCQA = "tiny.docqa"
+# the tiny cell that stands in for each real cell, by its mix's kind
+TINY_CELL = {"backlog": BACKLOG, "sessions": CHAT, "docqa": DOCQA}
+DOCQA_REPORTS = ("output_tok_s", "device_idle_pct")
 
 
 def with_family(cfg: dict) -> dict:
@@ -38,10 +41,24 @@ ENGINE = {"max_batch": 4, "max_seq_len": 256, "page_size": 16,
           "preempt_policy": "recompute"}
 
 
+DOCQA_MIX = {"kind": "docqa",
+             "documents": {"count": 4, "length": {
+                 "median": 96, "sigma": 0.4, "min": 48, "max": 160}},
+             "requests": 40, "block": 8,
+             "question": {"median": 12, "sigma": 0.6, "min": 4,
+                          "max": 40},
+             "output": {"median": 12, "sigma": 0.5, "min": 4, "max": 40},
+             "sampled_every": 0,
+             "setup_requests": [{"what": "documents",
+                                 "max_new_tokens": 1}],
+             "engine": dict(ENGINE, num_pages=64)}
+
+
 def make_checkout(root: str) -> str:
     """A checkout at ``root``: ``bench/`` copied, the tiny files added and
     a BENCHMARK.json whose cells are the tiny ones (the real metrics,
-    with their ``workloads`` moved onto the tiny cells)."""
+    with their ``workloads`` moved onto the tiny cell of the same kind of
+    mix)."""
     shutil.copytree(BENCH, os.path.join(root, "bench"),
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     b = os.path.join(root, "bench")
@@ -67,15 +84,19 @@ def make_checkout(root: str) -> str:
                    "setup_requests": [{"what": "system_prompts",
                                        "max_new_tokens": 1}],
                    "engine": dict(ENGINE, cow_pairs=2)}, f)
-    for c in (BACKLOG, CHAT):
+    with open(f"{b}/traffic/tiny-docqa.json", "w") as f:
+        json.dump(DOCQA_MIX, f)
+    for c in (BACKLOG, CHAT, DOCQA):
         with open(f"{b}/checks/{c}.json", "w") as f:
             json.dump({"max": {"logit_gap": 0.002},
                        "sample": {"min_tokens": 200, "max_requests": 8,
                                   "least_tokens": 8}}, f)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         man = json.load(f)
-    real = {w["name"]: (BACKLOG if w["traffic"] == "offline-decode"
-                        else CHAT) for w in man["workloads"]}
+    real = {}
+    for w in man["workloads"]:
+        with open(os.path.join(BENCH, "traffic", f"{w['traffic']}.json")) as f:
+            real[w["name"]] = TINY_CELL[json.load(f)["kind"]]
     man["configs"] = [{"name": "tiny", "source": "test",
                        "file": "bench/configs/tiny.json", "reduced": [],
                        "why": "test"}]
@@ -83,10 +104,16 @@ def make_checkout(root: str) -> str:
         {"name": BACKLOG, "config": "tiny", "traffic": "tiny-backlog",
          "chips": 1, "why": "test"},
         {"name": CHAT, "config": "tiny", "traffic": "tiny-chat",
+         "chips": 1, "why": "test"},
+        {"name": DOCQA, "config": "tiny", "traffic": "tiny-docqa",
          "chips": 1, "why": "test"}]
     for m in man["end_to_end"] + man["per_layer"]:
         if "workloads" in m:
             m["workloads"] = sorted({real[w] for w in m["workloads"]})
+        if DOCQA not in real.values() and m["name"] in DOCQA_REPORTS:
+            # no real docqa cell yet: the tiny one reports what the
+            # four-chip document-QA cell is to report
+            m["workloads"].append(DOCQA)
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(man, f)
     return root

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from conftest import BACKLOG, CHAT, ROOT, run_cell
+from conftest import BACKLOG, CHAT, DOCQA, ROOT, run_cell
 
 
 def test_sound_runs_are_correct(checkout):
@@ -75,6 +75,17 @@ def token_altered(core):
                          ids=lambda f: f.__name__)
 def test_broken_timed_path_is_not_correct(checkout, fault):
     rc, res = run_cell(checkout, BACKLOG, break_engine=fault)
+    assert rc == 0 and res is not None
+    assert res["correct"] is False, res["check"]
+
+
+@pytest.mark.parametrize("fault", [state_unchanged, half_batch,
+                                   token_altered],
+                         ids=lambda f: f.__name__)
+def test_broken_docqa_path_is_not_correct(checkout, fault):
+    """The same faults under the document-QA mix, whose requests decode
+    after a prefix-cache hit on a document prefilled in set-up."""
+    rc, res = run_cell(checkout, DOCQA, break_engine=fault)
     assert rc == 0 and res is not None
     assert res["correct"] is False, res["check"]
 

@@ -79,6 +79,34 @@ def test_collective_only_time():
     assert r.idle_by_host == {}
 
 
+def test_collective_exposed_pct_two_devices():
+    """Device 0's all-reduce runs alone 20 ns of a 100 ns window, device
+    1's 40 ns: the reader gives their mean share, 30%, and nothing for a
+    run on one chip or without a trace."""
+    import types
+
+    from conftest import ROOT
+    from manifest import Manifest
+    ar = "%all-reduce.2 = bf16[8] all-reduce()"
+    t = tr.Trace(
+        {"/device:TPU:0": {
+            "modules": [(0, 100, "jit_step(1)")],
+            "ops": [(0, 40, "%fusion.1 = bf16[8] fusion()"), (30, 70, ar),
+                    (60, 80, "%fusion.3 = bf16[8] fusion()")]},
+         "/device:TPU:1": {
+            "modules": [(0, 100, "jit_step(1)")],
+            "ops": [(0, 50, ar), (40, 100, "%fusion.4 = bf16[8] fusion()")]}},
+        [(0, 100, "window"), (0, 100, "step")])
+    r = tr.reduce_trace(t)
+    assert r.collective_exposed_s == pytest.approx(
+        {"/device:TPU:0": 20e-9, "/device:TPU:1": 40e-9})
+    read = Manifest(ROOT).reader("collective_exposed_pct")
+    assert read(types.SimpleNamespace(trace=r, chips=2)) \
+        == pytest.approx(30.0)
+    assert read(types.SimpleNamespace(trace=r, chips=1)) is None
+    assert read(types.SimpleNamespace(trace=None, chips=2)) is None
+
+
 def test_names():
     op = "%paged_flash_decode_fwd.9 = bf16[8,32,8,96] custom-call(s32[8,16])"
     assert tr.short_name(op) == "paged_flash_decode_fwd.9"
